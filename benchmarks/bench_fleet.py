@@ -74,8 +74,10 @@ def run_fleet_point(processes: int, *, plan: str, n_envs: int,
     if no_gather:
         cmd.append("--no-gather")
     t0 = time.perf_counter()
+    # several runner processes share this host, which only the CPU allows
     proc = subprocess.run(cmd, capture_output=True, text=True,
-                          timeout=timeout + 120, cwd=str(_ROOT))
+                          timeout=timeout + 120, cwd=str(_ROOT),
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     wall = time.perf_counter() - t0
     if proc.returncode != 0:
         raise RuntimeError(

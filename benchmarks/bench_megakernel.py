@@ -15,8 +15,9 @@ the committed PR-6 training baseline (``artifacts/BENCH_train.json``):
 - **golden drift**: Strouhal / C_D / C_L re-measured from the checked-in
   golden state (reuses ``bench_train.measure_golden_drift``),
 - **roofline gap**: measured interval time vs the roofline bound priced
-  against this host's :class:`~repro.launch.roofline.HardwareSpec` (CPU
-  hosts price against ``cpu_generic``, not silently against TPU numbers).
+  against this host's :class:`~repro.launch.roofline.HardwareSpec`, detected
+  from the device kind; a CPU host is priced only when the caller names it
+  (``REPRO_HW_SPEC=cpu_generic``), never silently at TPU numbers.
 
 Throughput is the best of ``REPS`` timed repetitions: the artifact records
 the machine's capability, not the co-tenancy noise of a shared host (each
@@ -25,7 +26,8 @@ rep is itself a full interval batch, ~0.2 s of work).
 Writes ``artifacts/BENCH_megakernel.json`` (``_smoke`` variant under
 ``--smoke`` — smoke artifacts never overwrite committed measurements).
 
-    PYTHONPATH=src python benchmarks/bench_megakernel.py [--smoke]
+    REPRO_HW_SPEC=cpu_generic PYTHONPATH=src \
+        python benchmarks/bench_megakernel.py [--smoke]      # on a CPU host
 """
 import argparse
 import json
@@ -123,8 +125,8 @@ def roofline_gap(throughput: dict) -> dict:
     pair touches every cell twice per iteration (~11 flops/cell/half-sweep,
     3 reads + 1 write per cell), the momentum predictor ~60 flops over both
     staggered fields with ~10 array passes, projection/correction ~15
-    flops/cell.  The bound uses this host's HardwareSpec — on the CPU
-    hosts that run this bench that is ``cpu_generic``, not TPU numbers.
+    flops/cell.  The bound uses this host's HardwareSpec — a CPU run must
+    name ``cpu_generic`` through ``$REPRO_HW_SPEC``.
     """
     c = throughput["config"]
     grid = GridConfig(res=c["res"], dt=0.01, poisson_iters=c["poisson_iters"])

@@ -26,6 +26,8 @@ def main() -> None:
     from benchmarks import (bench_cfd_scaling, bench_hybrid, bench_io,
                             bench_kernels, bench_roofline, bench_rollout,
                             bench_scenarios)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     suites = [
         ("fig7_cfd_scaling", bench_cfd_scaling.run),
         ("table1_hybrid", bench_hybrid.run),

@@ -17,6 +17,7 @@ from repro.cfd.grid import GridConfig
 from repro.drl.engine import SinkSpec
 from repro.drl.ppo import PPOConfig
 from repro.drl.train import TrainConfig, train
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -122,6 +123,7 @@ def main() -> None:
         sink=spec,
     )
     sink = spec.build()
+    enable_compile_cache()
     hist, params = train(cfg, sink=sink)
     if sink is not None:
         print(f"sink[{spec.kind}]: {sink.episodes} episodes, "

@@ -33,24 +33,17 @@ whose slab width or height is odd fall back to the legacy full-grid sweeps
 The domain-edge ghosts (Neumann at the inlet shard, Dirichlet at the outlet
 shard) are recomputed from the live local planes every sweep, exactly like
 the monolithic reference.
-
-jax 0.4.x caveat: the result keeps its mesh sharding, and *eager* op-by-op
-math on such an array can be silently wrong on the forced-multi-device CPU
-backend (observed with concatenate on a ("data">1, "model">1) mesh).  Every
-production path here consumes the result inside jit — whole-program
-partitioning is correct; ad-hoc analysis code should ``np.asarray`` the
-output first.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.cfd import poisson
-from repro.compat import shard_map
 
 
 def validate_decomposition(mesh, nx: int, axis: str = "model") -> int:
@@ -92,9 +85,9 @@ def ppermute_message_shapes(fn, *args, **kw):
     shapes = []
 
     def sub_jaxprs(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jax.extend.core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jax.extend.core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for item in v:
@@ -186,15 +179,9 @@ def _decomposed_solve_full(rhs, p0, *, mesh, axis, dx, dy, omega, iters,
 
         return jax.lax.fori_loop(0, outer, outer_body, p)
 
-    # check_vma=True (check_rep on jax 0.4.x) is load-bearing, not a debug
-    # aid: with the replication of unmentioned mesh axes UNchecked, jax
-    # 0.4.37's partitioner miscompiles this shard_map when it is embedded in
-    # a larger jitted program on a mesh whose "data" axis is > 1 (state
-    # corruption growing over a lax.scan).  Verified replication makes the
-    # same program correct on every mesh shape.
-    fn = shard_map(solve_local, mesh=mesh,
-                   in_specs=(P(None, axis), P(None, axis)),
-                   out_specs=P(None, axis), check_vma=True)
+    fn = jax.shard_map(solve_local, mesh=mesh,
+                       in_specs=(P(None, axis), P(None, axis)),
+                       out_specs=P(None, axis), check_vma=True)
     return fn(p0, rhs)
 
 
@@ -301,10 +288,9 @@ def _decomposed_solve_packed(rhs, p0, *, mesh, axis, dx, dy, omega, iters,
             red, black = jax.lax.fori_loop(0, outer, outer_body, (red, black))
         return poisson.unpack_checkerboard(red, black)
 
-    # check_vma=True is load-bearing — see _decomposed_solve_full
-    fn = shard_map(solve_local, mesh=mesh,
-                   in_specs=(P(None, axis), P(None, axis)),
-                   out_specs=P(None, axis), check_vma=True)
+    fn = jax.shard_map(solve_local, mesh=mesh,
+                       in_specs=(P(None, axis), P(None, axis)),
+                       out_specs=P(None, axis), check_vma=True)
     return fn(p0, rhs)
 
 

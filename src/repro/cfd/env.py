@@ -125,8 +125,8 @@ class CylinderEnv:
     once-per-shape warning).  ``backend="halo"`` with a ("data", "model") mesh
     runs each env's pressure solve as explicit x-slabs over the "model"
     axis (the plan's n_ranks).  Warmup always runs the un-decomposed
-    backend: its group batch is too small to tile the mesh "data" axis
-    (see decomp's jax 0.4.x caveat), and the two backends solve the same
+    backend: its group batch is too small to tile the mesh "data" axis,
+    and the two backends solve the same
     equations — the halo path's block-Jacobi boundary lag is a solver
     tolerance, not a different operator, so the developed flow and C_D0
     transfer."""
@@ -347,6 +347,8 @@ class CylinderEnv:
         flow_in = st.flow
         fz = faults.active("nan_env")
         if fz is not None:       # trace-time gate: absent in production traces
+            # the env's index in its vmapped batch: on a data-parallel mesh,
+            # each device's slice of the batch (RolloutEngine)
             idx = jax.lax.axis_index("env")
             hit = ((idx == int(fz.get("env", 0)))
                    & (st.t == int(fz.get("step", 0))))

@@ -30,6 +30,10 @@ from repro.core import backend as backend_mod
 # (registered so tests/conftest.py resets it between tests)
 _FUSED_VECTOR_WARNED = backend_mod.warn_once_cache()
 
+# the per-body contractions are physics: on TPU a default-precision f32 dot
+# runs in bf16 passes, which would round the actuation targets and forces
+_F32 = jax.lax.Precision.HIGHEST
+
 
 class FlowState(NamedTuple):
     u: jnp.ndarray
@@ -85,19 +89,38 @@ def init_state(cfg: GridConfig, geom: Geometry) -> FlowState:
 # boundary conditions (ghost-cell padding)
 # ---------------------------------------------------------------------------
 
+def _iota(a, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, a.shape, axis)
+
+
 def _apply_bc_u(u, inlet_u):
-    """In-array BCs for u: inlet Dirichlet, outlet zero-gradient."""
-    u = u.at[:, 0].set(inlet_u)
-    u = u.at[:, -1].set(u[:, -2])
-    return u
+    """In-array BCs for u: inlet Dirichlet, outlet zero-gradient.
+
+    Written as static slices and iota masks under ``where`` (not ``.at[]``
+    scatters or integer column indexing) so the same code lowers inside
+    the Pallas megakernel, whose TPU lowering has neither."""
+    col = _iota(u, 1)
+    u = jnp.where(col == u.shape[1] - 1, u[:, -2:-1], u)
+    return jnp.where(col == 0, jnp.reshape(inlet_u, (-1, 1)), u)
 
 
 def _apply_bc_v(v):
-    v = v.at[:, 0].set(0.0)            # inlet: v = 0
-    v = v.at[:, -1].set(v[:, -2])      # outlet: zero-gradient
-    v = v.at[0, :].set(0.0)            # bottom wall
-    v = v.at[-1, :].set(0.0)           # top wall
-    return v
+    """Inlet v = 0, outlet zero-gradient, no-slip bottom and top walls."""
+    col, row = _iota(v, 1), _iota(v, 0)
+    v = jnp.where(col == v.shape[1] - 1, v[:, -2:-1], v)
+    wall = (col == 0) | (row == 0) | (row == v.shape[0] - 1)
+    return jnp.where(wall, 0.0, v)
+
+
+def _project(cfg: GridConfig, ga: GeomArrays, u_bc, v_bc, p):
+    """Velocity correction ``u -= dt grad p`` on the interior faces, then the
+    boundary conditions.  Shared by ``step`` and the fused interval body."""
+    dt = cfg.dt
+    gx = u_bc[:, 1:-1] - dt * (p[:, 1:] - p[:, :-1]) / cfg.dx
+    gy = v_bc[1:-1, :] - dt * (p[1:, :] - p[:-1, :]) / cfg.dy
+    u_new = jnp.concatenate([u_bc[:, :1], gx, u_bc[:, -1:]], axis=1)
+    v_new = jnp.concatenate([v_bc[:1, :], gy, v_bc[-1:, :]], axis=0)
+    return _apply_bc_u(u_new, ga.inlet_u), _apply_bc_v(v_new)
 
 
 def _pad_u(u):
@@ -237,8 +260,8 @@ def _momentum(cfg: GridConfig, ga: GeomArrays, u, v, jet_vel, re, act_mode):
         # the same vector program inside a mixed multi-body batch
         a0 = av[0]
         m = act_mode
-        rot_t_u = jnp.einsum("b,byx->yx", av[:nb], ga.rotb_u)
-        rot_t_v = jnp.einsum("b,byx->yx", av[:nb], ga.rotb_v)
+        rot_t_u = jnp.einsum("b,byx->yx", av[:nb], ga.rotb_u, precision=_F32)
+        rot_t_v = jnp.einsum("b,byx->yx", av[:nb], ga.rotb_v, precision=_F32)
         tgt_u = (1 - m) * a0 * jet_tgt_u + m * rot_t_u
         tgt_v = (1 - m) * a0 * jet_tgt_v + m * rot_t_v
         pen_u = jnp.maximum(chi_u, (1 - m) * ga.jmask_u + m * ga.rmask_u)
@@ -248,10 +271,10 @@ def _momentum(cfg: GridConfig, ga: GeomArrays, u, v, jet_vel, re, act_mode):
     # momentum exchange -> force on the body (reaction), per unit density —
     # measured from the PREDICTOR u_star/v_star, before BCs touch the fields
     if per_body:
-        fx = -jnp.einsum("byx,yx->b", ga.own_u,
-                         (u_pen - u_star) / dt) * cfg.dx * cfg.dy
-        fy = -jnp.einsum("byx,yx->b", ga.own_v,
-                         (v_pen - v_star) / dt) * cfg.dx * cfg.dy
+        fx = -jnp.einsum("byx,yx->b", ga.own_u, (u_pen - u_star) / dt,
+                         precision=_F32) * cfg.dx * cfg.dy
+        fy = -jnp.einsum("byx,yx->b", ga.own_v, (v_pen - v_star) / dt,
+                         precision=_F32) * cfg.dx * cfg.dy
     else:
         fx = -jnp.sum((u_pen - u_star) / dt) * cfg.dx * cfg.dy
         fy = -jnp.sum((v_pen - v_star) / dt) * cfg.dx * cfg.dy
@@ -262,9 +285,10 @@ def _momentum(cfg: GridConfig, ga: GeomArrays, u, v, jet_vel, re, act_mode):
     # correction shifts that same column — one scatter chain per field
     # instead of penalize -> BC -> correct as three.
     influx = jnp.sum(inlet_u) * cfg.dy
-    outflux = jnp.sum(u_pen[:, -2]) * cfg.dy
-    out_col = u_pen[:, -2] + (influx - outflux) / (cfg.ny * cfg.dy)
-    u_bc = u_pen.at[:, 0].set(inlet_u).at[:, -1].set(out_col)
+    outflux = jnp.sum(u_pen[:, -2:-1]) * cfg.dy
+    out_col = u_pen[:, -2:-1] + (influx - outflux) / (cfg.ny * cfg.dy)
+    u_bc = jnp.concatenate([jnp.reshape(inlet_u, (-1, 1)), u_pen[:, 1:-1],
+                            out_col], axis=1)
     v_bc = _apply_bc_v(v_pen)
     return u_bc, v_bc, fx, fy
 
@@ -316,10 +340,7 @@ def step(cfg: GridConfig, geom_arrays: GeomArrays, state: FlowState, jet_vel,
                       omega=cfg.poisson_omega, p0=p,
                       backend="reference" if backend == "fused" else backend,
                       mesh=mesh, halo_inner=halo_inner)
-    u_new = u_bc.at[:, 1:-1].add(-dt * (p[:, 1:] - p[:, :-1]) / cfg.dx)
-    v_new = v_bc.at[1:-1, :].add(-dt * (p[1:, :] - p[:-1, :]) / cfg.dy)
-    u_new = _apply_bc_u(u_new, ga.inlet_u)
-    v_new = _apply_bc_v(v_new)
+    u_new, v_new = _project(cfg, ga, u_bc, v_bc, p)
 
     # force coefficients: 0.5 * rho * Ubar^2 * D = 0.5
     cd = fx / (0.5 * cfg.u_mean ** 2)

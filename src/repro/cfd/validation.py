@@ -15,6 +15,18 @@ import numpy as np
 from repro.cfd import solver
 from repro.cfd.grid import GridConfig, build_geometry
 
+# Relative tolerances of a golden re-measurement (tests/test_golden_physics.py
+# and chip_smoke.py).  On the generating platform the re-measurement is
+# bit-exact (0.0% on all three), so the slack only needs to cover
+# cross-platform float drift over the ~1600-step window of a stable limit
+# cycle.  Measured mutation sensitivities (development, restart window):
+#   upwind_blend 0.2->0.25:  St -1.6%          -> caught by TOL_ST
+#   upwind_blend 0.2->0.3:   St -3.0%, amp +2% -> caught by TOL_ST
+#   effective Re off by 10%: amp +9.6%         -> caught by TOL_AMP
+TOL_ST = 0.015
+TOL_CD = 0.01
+TOL_AMP = 0.05
+
 
 def run_uncontrolled(cfg: GridConfig, state: solver.FlowState, n: int,
                      *, backend: str = None, mesh=None,
@@ -25,19 +37,16 @@ def run_uncontrolled(cfg: GridConfig, state: solver.FlowState, n: int,
 
     ``backend``/``mesh`` select the Poisson backend (see ``cfd.poisson``),
     so the golden physics window can be re-measured through e.g. the
-    ``"halo"`` domain-decomposed path.  ``geometry`` picks the obstacle set
+    ``"halo"`` domain-decomposed path; the window is one
+    ``solver.step_interval``, so ``"fused"`` runs the fused interval (the
+    megakernel on TPU).  ``geometry`` picks the obstacle set
     (``grid.GEOMETRIES``); forces are the total over all bodies, which is
     what the golden fixtures pin."""
     geom_arrays = solver.geom_to_arrays(build_geometry(cfg, geometry))
-
-    def body(flow, _):
-        flow, out = solver.step(cfg, geom_arrays, flow, jnp.float32(0.0),
-                                backend=backend, mesh=mesh)
-        return flow, (out.cd, out.cl)
-
-    state, (cds, cls) = jax.jit(
-        lambda s: jax.lax.scan(body, s, None, length=n))(state)
-    return state, np.asarray(cds), np.asarray(cls)
+    state, out = jax.jit(lambda s: solver.step_interval(
+        cfg, geom_arrays, s, jnp.float32(0.0), n, backend=backend,
+        mesh=mesh))(state)
+    return state, np.asarray(out.cd), np.asarray(out.cl)
 
 
 def measure_shedding(cds: np.ndarray, cls: np.ndarray, dt: float

@@ -9,7 +9,8 @@ Three concerns that used to be triplicated across ``drl/train.py``,
   * mesh placement (paper §II.D): the env batch is sharded over the mesh
     "data" axis (the paper's N_envs) and each env's grid fields optionally
     over "model" (the paper's N_ranks domain decomposition).  XLA's SPMD
-    partitioner inserts the halo collective-permutes.
+    partitioner inserts the halo collective-permutes; a data-only plan runs
+    each device's envs as a program of its own (``shard_map``).
   * overlap: a double-buffered async mode where episode *e* is collected
     while the PPO update for episode *e-1*'s trajectories runs.  JAX async
     dispatch enqueues both computations back to back; the optimizer state is
@@ -349,10 +350,10 @@ def mesh_spans_processes(mesh: Optional[Mesh]) -> bool:
 def shard_env_batch(mesh: Mesh, st_b, n_ranks: int = 1):
     """device_put a batched env-state pytree with engine shardings.
 
-    Placing the batch on the mesh BEFORE the first collect is load-bearing
-    for the halo backend on jax 0.4.x: a batch left replicated over a
-    "data" axis of size > 1 trips the same partitioner miscompile the
-    decomp module documents.
+    Placing the batch on the mesh before the first collect puts each env
+    (and, for the halo backend, each x-slab of its grid fields) on its own
+    device once, so every collect compiles for and runs on those shardings
+    instead of a batch replicated over the "data" axis.
 
     On a process-spanning (fleet) mesh ``jax.device_put`` cannot place a
     host array, so each leaf is assembled with
@@ -525,13 +526,15 @@ class RolloutEngine:
 
     def _build_rollout(self):
         cfg, mesh = self.cfg, self.mesh
+        if mesh is not None and cfg.n_ranks == 1:
+            return self._build_data_parallel_rollout()
 
         def collect_traj(params, st_b, obs_b, key):
             if mesh is not None:
                 batch_spec, batch_space = env_state_specs(mesh)
 
-                def constrain(a):
-                    if cfg.n_ranks > 1 and is_grid_field(a, cfg.n_ranks):
+                def constrain(a):     # halo plans: n_ranks > 1
+                    if is_grid_field(a, cfg.n_ranks):
                         return jax.lax.with_sharding_constraint(
                             a, NamedSharding(mesh, batch_space))
                     return jax.lax.with_sharding_constraint(
@@ -543,6 +546,32 @@ class RolloutEngine:
                                             cfg.n_envs,
                                             obs_aux_fn=self.obs_aux_fn)
             return traj
+
+        return collect_traj
+
+    def _build_data_parallel_rollout(self):
+        """Data-only plans: each device rolls out its own slice of the env
+        batch as a program of its own (``shard_map`` over the "data" axis).
+        Envs never exchange data, and a Pallas kernel in the env step is a
+        Mosaic call the SPMD partitioner cannot split.  The per-env keys are
+        split before the batch is, so every env draws what it draws in the
+        unsharded rollout."""
+        cfg, mesh = self.cfg, self.mesh
+        batch_spec, _ = env_state_specs(mesh)
+
+        def local(params, st_b, obs_b, keys):
+            _, traj = rollout.rollout_keyed(self.env_step_fn, params, st_b,
+                                            obs_b, keys, cfg.horizon,
+                                            obs_aux_fn=self.obs_aux_fn)
+            return traj
+
+        run = jax.shard_map(local, mesh=mesh,
+                            in_specs=(P(), batch_spec, batch_spec, batch_spec),
+                            out_specs=batch_spec, check_vma=False)
+
+        def collect_traj(params, st_b, obs_b, key):
+            return run(params, st_b, obs_b,
+                       jax.random.split(key, cfg.n_envs))
 
         return collect_traj
 
@@ -589,10 +618,8 @@ class RolloutEngine:
         """One episode round of all N_envs environments.
 
         With a mesh, the env batch is pre-placed on it (a no-op when the
-        caller already did) — leaving a batch replicated over a "data" axis
-        of size > 1 trips the jax 0.4.x partitioner miscompile documented
-        in ``shard_env_batch``, so the engine owns the guard rather than
-        trusting every caller.
+        caller already did; see ``shard_env_batch``), so the engine owns the
+        placement rather than trusting every caller.
 
         Fleet mode: params/key arrive as process-local arrays, are
         replicated onto the global mesh for the distributed rollout, and

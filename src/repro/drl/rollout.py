@@ -61,7 +61,15 @@ def rollout_episode(env_step_fn, params, st0, obs0, key, length: int,
 def rollout_batch(env_step_fn, params, st0_b, obs0_b, key, length: int,
                   n_envs: int, *, obs_aux_fn=None):
     """vmapped over the environment axis (the paper's N_envs parallelism)."""
-    keys = jax.random.split(key, n_envs)
+    return rollout_keyed(env_step_fn, params, st0_b, obs0_b,
+                         jax.random.split(key, n_envs), length,
+                         obs_aux_fn=obs_aux_fn)
+
+
+def rollout_keyed(env_step_fn, params, st0_b, obs0_b, keys, length: int,
+                  *, obs_aux_fn=None):
+    """``rollout_batch`` with one key per env already split, so a slice of
+    the batch draws exactly the actions it draws inside the whole batch."""
     # axis_name lets the fault injector address a single env via
     # ``jax.lax.axis_index("env")``; with no collectives in the program it
     # is otherwise inert

@@ -209,15 +209,17 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
         if log_fn:
             log_fn(f"resume: {src} @ episode {int(ts.episode)}")
 
-    # pre-place the batch on the mesh (see shard_env_batch's docstring —
-    # required for correctness of the halo backend on jax 0.4.x).  For a
-    # resumed run this is the cross-plan re-sharding step.  Fleet
+    # pre-place the batch on the mesh (see shard_env_batch's docstring).
+    # For a resumed run this is the cross-plan re-sharding step.  Fleet
     # checkpoints snapshot the PRE-placement host copies: a process-spanning
     # global array cannot be pulled back to one host at save time.
     st_host = jax.tree.map(np.asarray, st_b) if fleet else None
     obs_host = np.asarray(obs_b) if fleet else None
     st_b = place_env_batch(mesh, st_b, engine.cfg.n_ranks)
     obs_b = place_env_batch(mesh, obs_b, 1)
+    if log_fn and mesh is not None:
+        log_fn(f"env batch: {n_envs} envs placed on "
+               f"{len(st_b.flow.u.sharding.device_set)} device(s)")
 
     if ts is None:
         params, optimizer, opt_state, key = engine.init(pcfg, cfg.ppo,

@@ -36,6 +36,47 @@ from repro.cfd.grid import GridConfig
 _N_GEOM = 11
 
 
+def _select(n: int, w: int, offset: int, transpose: bool = False):
+    """0/1 matrix picking full-grid column ``2k + offset`` for packed column
+    ``k``: shape (n, w), or (w, n) when ``transpose``."""
+    shape = (w, n) if transpose else (n, w)
+    i = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transpose else 0)
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transpose else 1)
+    return (i == 2 * k + offset).astype(jnp.float32)
+
+
+def _dot(a, b):
+    # every output sums one exact product with zeros, so at fp32 contract
+    # precision the selection is exact; a bf16 pass would round the fields
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _row_odd(ny: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (ny, 1), 0) % 2 == 1
+
+
+def pack_mxu(a):
+    """``poisson.pack_checkerboard`` as two selection matmuls.  Mosaic lowers
+    neither the (ny, nx//2, 2) reshape nor a lane-strided slice of an
+    unaligned plane; the MXU deinterleaves the columns instead."""
+    ny, nx = a.shape
+    even = _dot(a, _select(nx, nx // 2, 0))
+    odd = _dot(a, _select(nx, nx // 2, 1))
+    row_odd = _row_odd(ny)
+    return jnp.where(row_odd, odd, even), jnp.where(row_odd, even, odd)
+
+
+def unpack_mxu(red, black):
+    """Inverse of :func:`pack_mxu` (``poisson.unpack_checkerboard``)."""
+    ny, w = red.shape
+    row_odd = _row_odd(ny)
+    even = jnp.where(row_odd, black, red)
+    odd = jnp.where(row_odd, red, black)
+    return (_dot(even, _select(2 * w, w, 0, transpose=True))
+            + _dot(odd, _select(2 * w, w, 1, transpose=True)))
+
+
 def _fused_dt_kernel(*refs, cfg: GridConfig):
     from repro.cfd.solver import GeomArrays
     from repro.kernels.actuation.ops import fused_dt
@@ -48,7 +89,8 @@ def _fused_dt_kernel(*refs, cfg: GridConfig):
     ga = GeomArrays(*(r[...] for r in geom_refs))
     u2, v2, red2, black2, cd, cl = fused_dt(
         cfg, ga, u_ref[...], v_ref[...], red_ref[...], black_ref[...],
-        jet_ref[0, 0], re_ref[0, 0], mode_ref[0, 0])
+        jet_ref[0, 0], re_ref[0, 0], mode_ref[0, 0],
+        pack=pack_mxu, unpack=unpack_mxu)
     u_out[...] = u2
     v_out[...] = v2
     red_out[...] = red2
@@ -58,7 +100,7 @@ def _fused_dt_kernel(*refs, cfg: GridConfig):
 
 
 def fused_step(cfg: GridConfig, ga, u, v, red, black, jet_vel, re, act_mode,
-               *, interpret: bool = True):
+               *, interpret: bool):
     """One dt through the megakernel.  Mirrors ``ops.fused_dt``'s signature
     and return ``(u, v, red, black, cd, cl)``; scalars ride as (1, 1)
     operands so the whole dt is a single launch."""
@@ -66,8 +108,10 @@ def fused_step(cfg: GridConfig, ga, u, v, red, black, jet_vel, re, act_mode,
     scalar = lambda x: jnp.reshape(jnp.asarray(x, f32), (1, 1))
     # the megakernel serves the scalar-actuation path only (step_interval
     # falls back to the reference backend for per-body vector jets), so the
-    # per-body rotation targets / ownership masks never ride as kernel refs
-    ga = ga._replace(rotb_u=None, rotb_v=None, own_u=None, own_v=None)
+    # per-body rotation targets / ownership masks never ride as kernel refs;
+    # the inlet profile rides as a (ny, 1) column (Mosaic wants 2-D refs)
+    ga = ga._replace(rotb_u=None, rotb_v=None, own_u=None, own_v=None,
+                     inlet_u=jnp.reshape(ga.inlet_u, (-1, 1)))
     geom = [g for g in ga if g is not None]
     kern = functools.partial(_fused_dt_kernel, cfg=cfg)
     out_shape = [

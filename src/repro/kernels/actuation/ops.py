@@ -118,7 +118,7 @@ def packed_projection_planes(cfg: GridConfig, red, black, rhs_r, rhs_b):
     n_polish = min(10, iters // 2)
     n_sor = iters - n_polish
     omega = float(cfg.poisson_omega)
-    row_odd = (jnp.arange(cfg.ny) % 2 == 1)[:, None]
+    row_odd = jax.lax.broadcasted_iota(jnp.int32, (cfg.ny, 1), 0) % 2 == 1
 
     def body(i, planes):
         om = jnp.where(i < n_sor, omega, 1.0)
@@ -130,23 +130,23 @@ def packed_projection_planes(cfg: GridConfig, red, black, rhs_r, rhs_b):
 
 
 def fused_dt(cfg: GridConfig, ga: solver.GeomArrays, u, v, red, black,
-             jet_vel, re, act_mode):
+             jet_vel, re, act_mode, *, pack=poisson.pack_checkerboard,
+             unpack=poisson.unpack_checkerboard):
     """One dt with the pressure held packed: momentum (via the solver's own
     ``_momentum`` — one implementation) -> packed SOR projection ->
-    velocity correction.  Returns ``(u, v, red, black, cd, cl)``."""
-    dt = cfg.dt
+    velocity correction.  Returns ``(u, v, red, black, cd, cl)``.
+
+    ``pack``/``unpack`` convert the rhs and the pressure between the full
+    grid and the parity planes; the megakernel passes a layout that Mosaic
+    lowers (``kernel.pack_mxu``), which gives the same planes."""
     u_bc, v_bc, fx, fy = solver._momentum(cfg, ga, u, v, jet_vel, re,
                                           act_mode)
-    rhs = solver.divergence(u_bc, v_bc, cfg) / dt
-    rhs_r, rhs_b = poisson.pack_checkerboard(rhs)
+    rhs = solver.divergence(u_bc, v_bc, cfg) / cfg.dt
+    rhs_r, rhs_b = pack(rhs)
     red, black = packed_projection_planes(cfg, red, black, rhs_r, rhs_b)
     # the projection gradient needs full-grid adjacency; the planes stay the
     # carry — this unpack is a reshape/select XLA fuses into the correction
-    p = poisson.unpack_checkerboard(red, black)
-    u_new = u_bc.at[:, 1:-1].add(-dt * (p[:, 1:] - p[:, :-1]) / cfg.dx)
-    v_new = v_bc.at[1:-1, :].add(-dt * (p[1:, :] - p[:-1, :]) / cfg.dy)
-    u_new = solver._apply_bc_u(u_new, ga.inlet_u)
-    v_new = solver._apply_bc_v(v_new)
+    u_new, v_new = solver._project(cfg, ga, u_bc, v_bc, unpack(red, black))
     cd = fx / (0.5 * cfg.u_mean ** 2)
     cl = fy / (0.5 * cfg.u_mean ** 2)
     return u_new, v_new, red, black, cd, cl

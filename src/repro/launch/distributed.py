@@ -76,7 +76,6 @@ def fleet_env(coordinator: str, num_processes: int, process_id: int,
              if not f.startswith("--xla_force_host_platform_device_count")]
     flags.append(f"--xla_force_host_platform_device_count={n_total_devices}")
     env["XLA_FLAGS"] = " ".join(flags)
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
     env[ENV_COORDINATOR] = coordinator
     env[ENV_NUM_PROCESSES] = str(num_processes)
     env[ENV_PROCESS_ID] = str(process_id)

@@ -5,33 +5,20 @@ jax device state (required for the dry-run's forced 512-device host platform).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum) only exist in newer releases — pass them when available."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is not None
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-_make_mesh = make_mesh
+    """``jax.make_mesh`` with every axis in Auto (GSPMD-partitioned) mode."""
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-less mesh (spec computation only), across jax versions."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.AbstractMesh(
-            tuple(shape), tuple(axes), axis_types=(axis_type.Auto,) * len(axes))
-    # jax 0.4.x: AbstractMesh(((name, size), ...))
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """Device-less mesh (spec computation only)."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     axis_types=(_AUTO,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -42,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     principle; see README "Choosing a parallel plan")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many devices exist (tests / examples)."""
-    return _make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_for_plan(plan, devices=None, *, span_processes=None):

@@ -11,9 +11,10 @@ decode) anchors the "useful compute" ratio.
 The hardware constants the terms divide by are a :class:`HardwareSpec`, NOT
 module constants: every roofline is relative to a named device preset
 (``tpu_v5e``, ``cpu_generic``, ...), selected explicitly, via the
-``$REPRO_HW_SPEC`` environment variable, or detected from the running jax
-platform.  An unrecognized platform raises with the preset list instead of
-silently pricing the workload at TPU numbers.
+``$REPRO_HW_SPEC`` environment variable, or detected from the ``device_kind``
+JAX reports for the first device (:data:`DEVICE_KINDS`).  A device kind that
+is not in that table raises instead of silently pricing the workload at
+another chip's numbers.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ class HardwareSpec:
     hbm_bw: float                # main-memory bytes/s per device
     ici_bw: float                # interconnect bytes/s per link
     description: str = ""
+    source: str = ""             # where the peak numbers come from
 
     def to_dict(self) -> Dict:
         return {"name": self.name, "peak_flops": self.peak_flops,
@@ -51,11 +53,19 @@ HARDWARE_PRESETS: Dict[str, HardwareSpec] = {
     "tpu_v5e": HardwareSpec(
         name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
         description="TPU v5e chip: bf16 peak, HBM2e, ICI per link "
-                    "(~per direction)"),
+                    "(~per direction)",
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip ICI "
+               "(4 links)"),
     "cpu_generic": HardwareSpec(
         name="cpu_generic", peak_flops=5e10, hbm_bw=2e10, ici_bw=1e10,
         description="one generic x86 core: ~50 GFLOP/s sustained f32 FMA, "
                     "~20 GB/s sustained DRAM, loopback interconnect"),
+}
+
+# ``jax.Device.device_kind`` -> preset, for detection on the running host
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
 }
 
 # environment override consulted when no spec is passed explicitly
@@ -68,9 +78,10 @@ def hardware_spec(name: Union[None, str, HardwareSpec] = None
 
     Precedence: explicit ``name`` (a preset name or a HardwareSpec, passed
     through) > the ``$REPRO_HW_SPEC`` preset name > detection from the
-    running jax platform (tpu -> ``tpu_v5e``, cpu -> ``cpu_generic``).
-    Anything unrecognized raises a ValueError listing the presets — a
-    roofline against silently-wrong peak numbers is worse than no roofline.
+    first device's ``device_kind`` through :data:`DEVICE_KINDS`.  Anything
+    unrecognized raises a ValueError — a roofline against silently-wrong
+    peak numbers is worse than no roofline.  Detection never picks
+    ``cpu_generic``: a host CPU is priced only when the caller names it.
     """
     if isinstance(name, HardwareSpec):
         return name
@@ -84,13 +95,14 @@ def hardware_spec(name: Union[None, str, HardwareSpec] = None
                 f"unknown hardware spec {name!r}; choose a preset from "
                 f"{sorted(HARDWARE_PRESETS)} (or pass a HardwareSpec with "
                 f"your device's peak_flops/hbm_bw/ici_bw)") from None
-    platform = jax.default_backend()
-    detected = {"tpu": "tpu_v5e", "cpu": "cpu_generic"}.get(platform)
+    kind = jax.devices()[0].device_kind
+    detected = DEVICE_KINDS.get(kind)
     if detected is None:
         raise ValueError(
-            f"no hardware preset for jax platform {platform!r}; pass one of "
-            f"{sorted(HARDWARE_PRESETS)} explicitly (hw= / ${HW_SPEC_ENV}) "
-            f"or a HardwareSpec with your device's peak numbers")
+            f"no hardware preset for device_kind {kind!r} (known kinds: "
+            f"{sorted(DEVICE_KINDS)}); pass one of {sorted(HARDWARE_PRESETS)} "
+            f"explicitly (hw= / ${HW_SPEC_ENV}) or a HardwareSpec with your "
+            f"device's peak numbers")
     return HARDWARE_PRESETS[detected]
 
 
@@ -152,7 +164,7 @@ class Roofline:
     model_flops: float
     coll_by_kind: Dict[str, float]
     # the device the terms price against; None resolves through
-    # hardware_spec() (explicit > $REPRO_HW_SPEC > platform detection)
+    # hardware_spec() (explicit > $REPRO_HW_SPEC > device_kind detection)
     hw: Optional[HardwareSpec] = None
 
     def __post_init__(self):

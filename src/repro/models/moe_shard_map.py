@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.models import act_sharding
 from repro.models.layers import dtype_of
@@ -159,6 +157,6 @@ def apply_moe_expert_parallel(cfg: ModelConfig, p, x
     if has_shared:
         in_specs += [P("data", None), P("data", None), P(None, "data")]
         args += [p["shared"]["w1"], p["shared"]["w3"], p["shared"]["w2"]]
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=(x_spec, P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(*args)

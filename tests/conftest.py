@@ -10,6 +10,9 @@ if _SRC not in sys.path:
 
 # Tests must see 1 CPU device (the 512-device override is dryrun-only).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# Tests keep JAX's persistent compilation cache off, in this process and in
+# every child that inherits the environment (repro.launch.compile_cache).
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 import pytest

@@ -77,6 +77,53 @@ def test_decomposed_poisson_converges():
     assert "POISSON_OK" in out
 
 
+def test_data_parallel_collect_runs_each_env_slice_alone():
+    """A data-only plan rolls each device's envs out as a program of its
+    own: every env's trajectory is bitwise what the same env gives rolled
+    out alone with its key, for the reference and the Pallas backends."""
+    out = _run("""
+        import jax, numpy as np
+        from repro.cfd.env import CylinderEnv, EnvConfig
+        from repro.cfd.grid import GridConfig
+        from repro.core.plan import ParallelPlan
+        from repro.drl import networks, rollout
+        from repro.drl.engine import (EngineConfig, RolloutEngine,
+                                      broadcast_env_state, place_env_batch)
+        from repro.launch.mesh import mesh_for_plan
+        ecfg = EnvConfig(grid=GridConfig(res=6, dt=0.012, poisson_iters=40),
+                         steps_per_action=4, actions_per_episode=3,
+                         warmup_time=1.5)
+        st0, obs0 = CylinderEnv(ecfg).reset()
+        st_b, obs_b = broadcast_env_state(st0, obs0, 8)
+        params = networks.init_actor_critic(
+            networks.PolicyConfig(obs_dim=int(obs_b.shape[-1]), act_dim=1),
+            jax.random.PRNGKey(0))
+        key = jax.random.PRNGKey(1)
+        keys = jax.random.split(key, 8)
+        mesh = mesh_for_plan(ParallelPlan(4, 4, 1))
+        for backend in ("reference", "pallas"):
+            env = CylinderEnv(ecfg, backend=backend, mesh=mesh)
+            eng = RolloutEngine.for_env(env, EngineConfig(n_envs=8,
+                                                          horizon=3),
+                                        mesh=mesh)
+            _, traj = eng.collect(params, place_env_batch(mesh, st_b),
+                                  place_env_batch(mesh, obs_b), key,
+                                  record=False)
+            assert len(traj.reward.sharding.device_set) == 4
+            for dev in range(4):
+                sl = slice(2 * dev, 2 * dev + 2)
+                _, alone = jax.jit(lambda s, o, k: rollout.rollout_keyed(
+                    env.env_step, params, s, o, k, 3))(
+                    jax.tree.map(lambda a: a[sl], st_b), obs_b[sl], keys[sl])
+                for f in ("obs", "act", "reward", "cd", "cl"):
+                    np.testing.assert_array_equal(
+                        np.asarray(getattr(traj, f))[sl],
+                        np.asarray(getattr(alone, f)), err_msg=f)
+        print("DP_OK")
+    """)
+    assert "DP_OK" in out
+
+
 def test_train_step_lowers_on_multidevice_mesh():
     out = _run("""
         import jax, jax.numpy as jnp

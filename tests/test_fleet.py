@@ -26,7 +26,8 @@ LAUNCHER = str(ROOT / "tools" / "launch_fleet.py")
 
 def _launch(workdir, *extra, processes=1, episodes=2, timeout=600):
     env = {"JAX_PLATFORMS": "cpu", "PYTHONPATH": SRC,
-           "PATH": "/usr/bin:/bin", "HOME": "/tmp"}
+           "PATH": "/usr/bin:/bin", "HOME": "/tmp",
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     out = subprocess.run(
         [sys.executable, LAUNCHER, "--processes", str(processes),
          "--episodes", str(episodes), "--workdir", str(workdir),
@@ -172,6 +173,24 @@ def test_fleet_env_pins_device_count():
     assert env[ENV_COORDINATOR] == "127.0.0.1:1234"
     assert env[ENV_NUM_PROCESSES] == "2" and env[ENV_PROCESS_ID] == "1"
     assert env[ENV_FLEET] == "1"
+    # the platform is the caller's choice: nothing here selects the CPU
+    assert "JAX_PLATFORMS" not in env
+    cpu = fleet_env("127.0.0.1:1234", 2, 1, n_total_devices=8,
+                    base={"JAX_PLATFORMS": "cpu"})
+    assert cpu["JAX_PLATFORMS"] == "cpu"
+
+
+def test_launch_fleet_refuses_several_runners_off_cpu(tmp_path):
+    """One chip belongs to one process: several runners on one host start
+    only when the caller chose the CPU, and the refusal says why."""
+    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/tmp"}
+    out = subprocess.run(
+        [sys.executable, LAUNCHER, "--processes", "2",
+         "--workdir", str(tmp_path)],
+        capture_output=True, text=True, env=env, timeout=120)
+    assert out.returncode != 0
+    assert "JAX_PLATFORMS=cpu" in out.stderr
+    assert not list(tmp_path.glob("runner_*.log"))      # nothing started
 
 
 def test_initialize_fleet_single_process_noop():

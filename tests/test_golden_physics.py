@@ -19,20 +19,10 @@ import pytest
 
 from repro.cfd import solver
 from repro.cfd.grid import GridConfig
-from repro.cfd.validation import measure_shedding, run_uncontrolled
+from repro.cfd.validation import (TOL_AMP, TOL_CD, TOL_ST, measure_shedding,
+                                  run_uncontrolled)
 
 GOLDEN = Path(__file__).parent / "golden" / "cyl_re100_res8.npz"
-
-# Relative tolerances.  On the generating platform the re-measurement is
-# bit-exact (0.0% on all three), so the slack only needs to cover
-# cross-platform float drift over the ~1600-step window of a stable limit
-# cycle.  Measured mutation sensitivities (development, restart window):
-#   upwind_blend 0.2->0.25:  St -1.6%          -> caught by TOL_ST
-#   upwind_blend 0.2->0.3:   St -3.0%, amp +2% -> caught by TOL_ST
-#   effective Re off by 10%: amp +9.6%         -> caught by TOL_AMP
-TOL_ST = 0.015
-TOL_CD = 0.01
-TOL_AMP = 0.05
 
 
 @pytest.fixture(scope="module")

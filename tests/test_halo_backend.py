@@ -5,10 +5,6 @@ golden-physics tolerances at n_ranks=2, and the executable-plan train() path.
 Subprocess pattern follows tests/test_distributed.py: the parent test run
 must see 1 device, so everything needing a real mesh runs in a child with
 XLA_FLAGS=--xla_force_host_platform_device_count=4.
-
-NOTE on comparisons: results of the decomposed solve are pulled to host
-(np.asarray) before any further math — eager op-by-op computation on a
-mesh-sharded array is miscompiled by jax 0.4.x (see cfd/decomp.py).
 """
 import subprocess
 import sys
@@ -186,12 +182,13 @@ def test_halo_engine_mixed_scenario_batch():
 def test_halo_golden_physics_at_two_ranks():
     """Acceptance criterion: trajectories integrated through the halo
     backend at n_ranks=2 stay inside the golden-physics tolerances
-    (same constants as tests/test_golden_physics.py)."""
+    (the golden test's tolerances, from cfd.validation)."""
     out = _run(f"""
         import numpy as np
         from repro.cfd import solver
         from repro.cfd.grid import GridConfig
-        from repro.cfd.validation import measure_shedding, run_uncontrolled
+        from repro.cfd.validation import (TOL_AMP, TOL_CD, TOL_ST,
+                                          measure_shedding, run_uncontrolled)
         from repro.launch.mesh import mesh_for_plan
 
         ref = np.load({GOLDEN!r})
@@ -202,7 +199,6 @@ def test_halo_golden_physics_at_two_ranks():
         _, cds, cls = run_uncontrolled(cfg, state, int(ref["meas_steps"]),
                                        backend="halo", mesh=mesh)
         stats = measure_shedding(cds, cls, cfg.dt)
-        TOL_ST, TOL_CD, TOL_AMP = 0.015, 0.01, 0.05   # = golden test gates
         def rel(a, b):
             return abs(a - b) / abs(b)
         errs = dict(st=rel(stats["strouhal"], float(ref["strouhal"])),

@@ -94,12 +94,18 @@ def test_hardware_spec_presets_and_resolution(monkeypatch):
     assert roofline.hardware_spec("tpu_v5e").peak_flops == 197e12
     custom = roofline.HardwareSpec("lab_gpu", 1e12, 1e11, 1e10)
     assert roofline.hardware_spec(custom) is custom
-    # environment override beats platform detection
-    monkeypatch.setenv(roofline.HW_SPEC_ENV, "tpu_v5e")
-    assert roofline.hardware_spec().name == "tpu_v5e"
-    monkeypatch.delenv(roofline.HW_SPEC_ENV)
-    # this suite pins JAX_PLATFORMS=cpu -> detection lands on cpu_generic
+    # environment override beats device detection
+    monkeypatch.setenv(roofline.HW_SPEC_ENV, "cpu_generic")
     assert roofline.hardware_spec().name == "cpu_generic"
+    monkeypatch.delenv(roofline.HW_SPEC_ENV)
+    # detection keys on the device_kind JAX reports, with a cited source
+    v5e = roofline.HARDWARE_PRESETS[roofline.DEVICE_KINDS["TPU v5 lite"]]
+    assert v5e.name == "tpu_v5e" and "819 GB/s" in v5e.source
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+    monkeypatch.setattr(roofline.jax, "devices", lambda: [_Dev()])
+    assert roofline.hardware_spec().name == "tpu_v5e"
 
 
 def test_hardware_spec_unknown_is_actionable(monkeypatch):
@@ -108,6 +114,11 @@ def test_hardware_spec_unknown_is_actionable(monkeypatch):
     # a bad env override fails the same way instead of silently defaulting
     monkeypatch.setenv(roofline.HW_SPEC_ENV, "nonsense")
     with pytest.raises(ValueError, match="unknown hardware spec"):
+        roofline.hardware_spec()
+    # this suite pins JAX_PLATFORMS=cpu: the host CPU is no known device
+    # kind, so detection raises instead of pricing it as any preset
+    monkeypatch.delenv(roofline.HW_SPEC_ENV)
+    with pytest.raises(ValueError, match="no hardware preset for device_kind"):
         roofline.hardware_spec()
 
 
@@ -181,3 +192,28 @@ def test_fp8_cache_dtype():
     cfg2 = get_config("phi4-mini-3.8b")
     cache2 = jax.eval_shape(lambda: M.init_cache(cfg2, 2, 64))
     assert cache2["k"].dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets no directory of its
+    own; unset, the cache sits at the fixed <checkout>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    checkout = Path(__file__).resolve().parent.parent
+    try:
+        if from_env:
+            monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
+            where = compile_cache.enable_compile_cache()
+            assert where == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == where
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    # the suite keeps the cache off (tests/conftest.py)
+    assert not jax.config.jax_enable_compilation_cache

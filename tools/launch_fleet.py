@@ -14,8 +14,14 @@ survivors, shrinks the fleet to the next process count that still divides
 the plan, and relaunches with ``resume="auto"`` — training continues from
 the latest durable checkpoint on the smaller fleet, same plan, same bits.
 
-    PYTHONPATH=src python tools/launch_fleet.py --processes 2 --episodes 4
-    PYTHONPATH=src python tools/launch_fleet.py --smoke      # CI gate
+A chip belongs to one process, so all runners of this one-host launcher can
+share it only when they run on the CPU: with more than one runner the
+caller must set ``JAX_PLATFORMS=cpu`` itself, and the launcher refuses to
+start otherwise.  The supervisor never touches JAX.
+
+    JAX_PLATFORMS=cpu PYTHONPATH=src python tools/launch_fleet.py \
+        --processes 2 --episodes 4
+    JAX_PLATFORMS=cpu PYTHONPATH=src python tools/launch_fleet.py --smoke
 
 Machine-readable lines on stdout (tests/bench parse these):
     FLEET_SHRINK gen=<g> procs=<old>-><new> reason=<exit|stale>
@@ -109,8 +115,10 @@ def parse_args(argv=None):
 
 def run_runner(args) -> None:
     from repro.launch import distributed as dist
+    from repro.launch.compile_cache import enable_compile_cache
 
     info = dist.initialize_fleet()       # from the REPRO_* env vars
+    enable_compile_cache()
 
     from repro.cfd.env import EnvConfig
     from repro.cfd.grid import GridConfig
@@ -300,6 +308,12 @@ def run_supervisor(args) -> int:
     workdir.mkdir(parents=True, exist_ok=True)
     n_total, _, n_ranks = args.plan_tuple
     procs, gen = args.processes, 0
+    if procs > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.exit(f"--processes {procs} would start {procs} runners on this "
+                 f"host, but an accelerator belongs to one process; set "
+                 f"JAX_PLATFORMS=cpu to run a multi-process fleet on the "
+                 f"CPU, or use --processes 1 (one process drives every "
+                 f"local chip)")
     if n_total % procs or (n_total // procs) % n_ranks:
         sys.exit(f"--processes {procs} does not divide plan {args.plan} "
                  f"with intra-host halos; viable sizes divide n_total="

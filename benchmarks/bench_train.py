@@ -1,18 +1,19 @@
 """End-to-end training-loop benchmark: the observability artifact.
 
 Runs the paper's Fig. 4 loop (collect -> PPO update -> trajectory sink) on
-the cylinder env with ``EngineConfig(timing=True)`` so the engine reports
-real phase times, and measures:
+the cylinder env and measures:
 
-- **throughput**: environment steps (solver steps x envs) per second,
-- **phase shares**: collect / update / sink-write fractions of wall time
-  (the paper's ">95% of time is CFD" claim, Fig. 10),
-- **projected parallel efficiency**: a strong-scaling projection of this
-  host's phase split to the paper's 60-core point (collect parallelizes,
-  update + sink stay serial) against the paper's measured 78% / 47x,
+- **throughput**: environment steps (solver steps x envs) per second over
+  the whole loop,
+- **sink-write share**: the trajectory sink's own write time
+  (``TrajectorySink.time_spent``) as a fraction of wall time,
 - **golden-physics drift**: Strouhal / mean C_D / C_L amplitude re-measured
   from the checked-in golden state vs the stored reference — the dashboard
   sees solver drift next to the perf numbers that might have caused it.
+
+Where the loop's time goes inside an episode is read from a profiler trace
+of it (the ``repro/...`` spans of ``repro.drl.spans``), not from timers
+here: a timer that syncs would change the loop it measures.
 
 Writes ``artifacts/BENCH_train.json`` (``BENCH_train_smoke.json`` with
 ``--smoke`` — smoke artifacts never overwrite committed measurements).
@@ -41,9 +42,7 @@ from repro.drl.engine import (EngineConfig, RolloutEngine, SinkSpec,
 from repro.drl.ppo import PPOConfig
 from repro.drl.train_state import code_fingerprint
 
-BENCH_SCHEMA = "repro.bench_train/v1"
-PAPER_EFFICIENCY_60 = 0.78      # paper Fig. 7: parallel efficiency, 60 cores
-PAPER_SPEEDUP_60 = 47.0         # paper: 47x at 60 cores
+BENCH_SCHEMA = "repro.bench_train/v2"
 GOLDEN = Path(__file__).resolve().parent.parent / "tests" / "golden" \
     / "cyl_re100_res8.npz"
 
@@ -69,7 +68,7 @@ def measure_training(smoke: bool) -> dict:
     root = tempfile.mkdtemp(prefix="bench_train_sink_")
     engine = RolloutEngine.for_env(
         env, EngineConfig(n_envs=n_envs, horizon=horizon, gamma=ppo.gamma,
-                          lam=ppo.lam, timing=True,
+                          lam=ppo.lam,
                           sink=SinkSpec(kind="dataset", root=root)))
     st_b, obs_b = broadcast_env_state(st, obs, n_envs)
     params, optimizer, opt_state, key = engine.init(pcfg, ppo, seed=0)
@@ -77,7 +76,6 @@ def measure_training(smoke: bool) -> dict:
     # one untimed episode: compile collect + postprocess + update outside
     # the measured window (throughput, not compile latency)
     engine.run_sync(params, opt_state, ppo, optimizer, st_b, obs_b, key, 1)
-    engine.stats = {"collect_s": 0.0, "update_s": 0.0, "episodes": 0}
     sink = engine.sink
     write0, bytes0 = sink.time_spent, sink.bytes_written
 
@@ -86,26 +84,11 @@ def measure_training(smoke: bool) -> dict:
                     episodes)
     wall = time.perf_counter() - t0
 
-    collect_s = engine.stats["collect_s"]
-    update_s = engine.stats["update_s"]
     sink_s = sink.time_spent - write0
     sink_bytes = sink.bytes_written - bytes0
     shutil.rmtree(root, ignore_errors=True)
 
     env_steps = n_envs * horizon * spa * episodes
-    per_ep = {"collect_s": collect_s / episodes,
-              "update_s": update_s / episodes,
-              "sink_write_s": sink_s / episodes}
-
-    # strong-scaling projection of THIS host's phase split: collect (the CFD
-    # side) parallelizes over cores, update + sink stay serial — the Amdahl
-    # shape behind the paper's Fig. 7 curve.  t(n) = collect/n + serial.
-    serial = per_ep["update_s"] + per_ep["sink_write_s"]
-    t1 = per_ep["collect_s"] + serial
-
-    def eff(n):
-        return t1 / (n * (per_ep["collect_s"] / n + serial))
-
     return {
         "config": {"res": res, "poisson_iters": p_iters, "n_envs": n_envs,
                    "horizon": horizon, "steps_per_action": spa,
@@ -116,22 +99,11 @@ def measure_training(smoke: bool) -> dict:
         "env_steps": env_steps,
         "env_steps_per_s": env_steps / wall,
         "episodes_per_s": episodes / wall,
-        "shares": {"collect": collect_s / wall, "update": update_s / wall,
-                   "sink_write": sink_s / wall,
-                   "other": max(0.0, 1.0 - (collect_s + update_s + sink_s)
-                                / wall)},
-        "per_episode_s": per_ep,
+        "shares": {"sink_write": sink_s / wall},
         "sink": {"kind": "dataset", "bytes_written": sink_bytes,
                  "bytes_per_episode": sink_bytes / episodes,
+                 "write_s_per_episode": sink_s / episodes,
                  "write_bandwidth": sink_bytes / sink_s if sink_s else None},
-        "scaling_projection": {
-            "model": "t(n) = collect/n + update + sink (strong scaling)",
-            "projected_speedup_60": 60.0 * eff(60),
-            "projected_efficiency_60": eff(60),
-            "projected_efficiency_8": eff(8),
-            "paper_efficiency_60": PAPER_EFFICIENCY_60,
-            "paper_speedup_60": PAPER_SPEEDUP_60,
-        },
     }
 
 
@@ -177,14 +149,9 @@ def run(smoke: bool = False, out: str = None) -> dict:
     path.parent.mkdir(parents=True, exist_ok=True)
     path.write_text(json.dumps(record, indent=1, sort_keys=True))
 
-    sh, proj = record["shares"], record["scaling_projection"]
     print(f"train: {record['env_steps_per_s']:.1f} env-steps/s "
-          f"({record['wall_s']:.2f}s wall)")
-    print(f"shares: collect {sh['collect']:.1%}  update {sh['update']:.1%}  "
-          f"sink {sh['sink_write']:.1%}  other {sh['other']:.1%}")
-    print(f"projected efficiency @60 cores: "
-          f"{proj['projected_efficiency_60']:.1%} "
-          f"(paper: {PAPER_EFFICIENCY_60:.0%}, {PAPER_SPEEDUP_60:.0f}x)")
+          f"({record['wall_s']:.2f}s wall), sink writes "
+          f"{record['shares']['sink_write']:.1%} of it")
     gd = record["golden_drift"]
     if "error" in gd:
         print(f"golden drift: skipped ({gd['error']})")

@@ -315,6 +315,7 @@ class CylinderEnv:
                 self._group_cache[g] = (solver.FlowState(*flow),
                                         float(cd0s[i]))
 
+    @jax.named_scope("probes")
     def _observe(self, st: EnvState) -> jnp.ndarray:
         return probes_mod.sample_pressure(st.scn.probe_ij, st.flow.p,
                                           st.scn.probe_mask)
@@ -365,20 +366,21 @@ class CylinderEnv:
                                           act_mode=st.scn.act_mode,
                                           backend=self.backend,
                                           mesh=self.mesh)
-        if outs.cd.ndim > 1:
-            # per-body (n_steps, B) coefficients: the reward drag term is the
-            # total, but lift is penalized per body — opposite-signed body
-            # lifts must not cancel into a spurious zero penalty
-            cd_b = jnp.mean(outs.cd, axis=0)
-            cl_b = jnp.mean(outs.cl, axis=0)
-            cd = jnp.sum(cd_b)
-            cl = jnp.sum(cl_b)
-            cl_pen = jnp.sum(jnp.abs(cl_b))
-        else:
-            cd = jnp.mean(outs.cd)
-            cl = jnp.mean(outs.cl)
-            cl_pen = jnp.abs(cl)
-        reward = st.scn.cd0 - cd - cfg.reward_omega * cl_pen   # eq. (12)
+        with jax.named_scope("reward"):
+            if outs.cd.ndim > 1:
+                # per-body (n_steps, B) coefficients: the reward drag term is
+                # the total, but lift is penalized per body — opposite-signed
+                # body lifts must not cancel into a spurious zero penalty
+                cd_b = jnp.mean(outs.cd, axis=0)
+                cl_b = jnp.mean(outs.cl, axis=0)
+                cd = jnp.sum(cd_b)
+                cl = jnp.sum(cl_b)
+                cl_pen = jnp.sum(jnp.abs(cl_b))
+            else:
+                cd = jnp.mean(outs.cd)
+                cl = jnp.mean(outs.cl)
+                cl_pen = jnp.abs(cl)
+            reward = st.scn.cd0 - cd - cfg.reward_omega * cl_pen  # eq. (12)
         if st.reset_flow is None:     # sentinel off: the pre-guard program
             st2 = EnvState(flow=flow, jet_vel=jet, t=st.t + 1, scn=st.scn)
             return st2, EnvOutput(obs=self._observe(st2), reward=reward,

@@ -187,6 +187,7 @@ def packed_sweep_pair(red, black, rhs_r, rhs_b, om, *, dx, dy, row_odd):
 @functools.partial(jax.jit, static_argnames=("dx", "dy", "iters", "omega_s",
                                              "backend", "polish", "mesh",
                                              "halo_axis", "halo_inner"))
+@jax.named_scope("poisson")
 def _solve_impl(rhs, p0, omega_t, dx, dy, *, iters: int, omega_s, backend: str,
                 polish: int, mesh, halo_axis: str, halo_inner: int):
     # omega arrives on exactly one of two lanes: ``omega_s`` (static Python

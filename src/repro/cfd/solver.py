@@ -112,6 +112,7 @@ def _apply_bc_v(v):
     return jnp.where(wall, 0.0, v)
 
 
+@jax.named_scope("projection")
 def _project(cfg: GridConfig, ga: GeomArrays, u_bc, v_bc, p):
     """Velocity correction ``u -= dt grad p`` on the interior faces, then the
     boundary conditions.  Shared by ``step`` and the fused interval body."""
@@ -196,6 +197,7 @@ def divergence(u, v, cfg: GridConfig):
 # one time step
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("momentum")
 def _momentum(cfg: GridConfig, ga: GeomArrays, u, v, jet_vel, re, act_mode):
     """The momentum half of one dt: explicit advect-diffuse predictor,
     implicit volume penalization, and the fused BC/outlet-mass-correction
@@ -270,14 +272,15 @@ def _momentum(cfg: GridConfig, ga: GeomArrays, u, v, jet_vel, re, act_mode):
     v_pen = (v_star + lam * pen_v * tgt_v) / (1 + lam * pen_v)
     # momentum exchange -> force on the body (reaction), per unit density —
     # measured from the PREDICTOR u_star/v_star, before BCs touch the fields
-    if per_body:
-        fx = -jnp.einsum("byx,yx->b", ga.own_u, (u_pen - u_star) / dt,
-                         precision=_F32) * cfg.dx * cfg.dy
-        fy = -jnp.einsum("byx,yx->b", ga.own_v, (v_pen - v_star) / dt,
-                         precision=_F32) * cfg.dx * cfg.dy
-    else:
-        fx = -jnp.sum((u_pen - u_star) / dt) * cfg.dx * cfg.dy
-        fy = -jnp.sum((v_pen - v_star) / dt) * cfg.dx * cfg.dy
+    with jax.named_scope("forces"):
+        if per_body:
+            fx = -jnp.einsum("byx,yx->b", ga.own_u, (u_pen - u_star) / dt,
+                             precision=_F32) * cfg.dx * cfg.dy
+            fy = -jnp.einsum("byx,yx->b", ga.own_v, (v_pen - v_star) / dt,
+                             precision=_F32) * cfg.dx * cfg.dy
+        else:
+            fx = -jnp.sum((u_pen - u_star) / dt) * cfg.dx * cfg.dy
+            fy = -jnp.sum((v_pen - v_star) / dt) * cfg.dx * cfg.dy
 
     # 3. boundary conditions + global outlet mass correction, fused into one
     # pass over each field: the inlet BC pins column 0 to inlet_u (so the

@@ -47,7 +47,7 @@ from repro.ckpt.io import atomic_write_bytes, retry_io
 from repro.testing import faults
 from repro.core import backend as backend_mod
 from repro.core.interface import pack_arrays, unpack_arrays
-from repro.drl import networks, rollout
+from repro.drl import networks, rollout, spans
 from repro.drl.gae import gae_batch
 from repro.drl.ppo import Batch, PPOConfig, make_optimizer, ppo_update
 from repro.drl.rollout import Trajectory
@@ -92,7 +92,8 @@ class TrajectorySink:
 
     def write(self, episode: int, traj: Trajectory) -> int:
         t0 = time.perf_counter()
-        n = self._write(episode, traj)
+        with spans.span("io.sink"):
+            n = self._write(episode, traj)
         self.bytes_written += n
         self.time_spent += time.perf_counter() - t0
         self.episodes += 1
@@ -423,10 +424,6 @@ class EngineConfig:
     plan: Any = None
     # trajectory spill (SinkSpec); an explicit sink= to the engine wins
     sink: Optional[SinkSpec] = None
-    # phase timing: block_until_ready around collect/update so
-    # ``engine.stats`` reports real collect/update/sink-write shares
-    # (benchmarks opt in; training loops keep async dispatch by default)
-    timing: bool = False
     # multi-process fleet mode (repro.launch.distributed): the rollout runs
     # on the process-spanning mesh, trajectories are all-gathered to the
     # host, and postprocess + PPO update run as a REPLICATED local
@@ -470,7 +467,6 @@ class RolloutEngine:
             sink = cfg.sink.build()
         self.sink = sink
         self.episode = 0
-        self.stats = {"collect_s": 0.0, "update_s": 0.0, "episodes": 0}
         rollout_fn = self._build_rollout()
         postprocess_fn = self._build_postprocess()
 
@@ -587,11 +583,13 @@ class RolloutEngine:
                 aux_n = {"xy": traj.probe_xy, "mask": traj.probe_mask}
             else:
                 aux_t = aux_n = None
-            values = networks.value(params, traj.obs, aux_t)     # (N, T)
-            last_v = networks.value(params, traj.last_obs, aux_n)  # (N,)
-            adv, ret = gae_batch(traj.reward, values, last_v,
-                                 gamma=cfg.gamma, lam=cfg.lam,
-                                 valid=traj.valid)
+            with jax.named_scope("values"):
+                values = networks.value(params, traj.obs, aux_t)   # (N, T)
+                last_v = networks.value(params, traj.last_obs, aux_n)  # (N,)
+            with jax.named_scope("gae"):
+                adv, ret = gae_batch(traj.reward, values, last_v,
+                                     gamma=cfg.gamma, lam=cfg.lam,
+                                     valid=traj.valid)
             flat = lambda x: x.reshape((-1,) + x.shape[2:])
             batch = Batch(obs=flat(traj.obs), act=flat(traj.act),
                           logp_old=flat(traj.logp), adv=flat(adv),
@@ -627,32 +625,17 @@ class RolloutEngine:
         ``postprocess`` then compiles as a plain local program, identical
         on every process and at every fleet size (the bitwise contract).
         The returned Trajectory is the host copy (full batch)."""
-        if self.mesh is not None:
-            st_b = shard_env_batch(self.mesh, st_b, self.cfg.n_ranks)
-        t0 = time.perf_counter()
-        if self.cfg.fleet:
-            # REPRO_FLEET_TIMING=1 splits collect into rollout/gather wall
-            # time (engine.stats) — the extra local sync it inserts slightly
-            # perturbs the overlap, so it stays off outside diagnostics
-            _timing = os.environ.get("REPRO_FLEET_TIMING")
-            traj = self._rollout(self._replicate(params), st_b, obs_b,
-                                 self._replicate(key))
-            if _timing:
-                jax.block_until_ready(traj)
-                self.stats["rollout_s"] = (self.stats.get("rollout_s", 0.0)
-                                           + time.perf_counter() - t0)
-                t0 = time.perf_counter()
-            traj = _host_traj(self._gather(traj))
-            if _timing:
-                self.stats["gather_s"] = (self.stats.get("gather_s", 0.0)
-                                          + time.perf_counter() - t0)
-        else:
-            traj = self._rollout(params, st_b, obs_b, key)
-        batch = self.postprocess(params, traj)
-        if self.cfg.timing:
-            jax.block_until_ready(batch)
-            self.stats["collect_s"] += time.perf_counter() - t0
-            self.stats["episodes"] += 1
+        with spans.span("collect"):
+            if self.mesh is not None:
+                st_b = shard_env_batch(self.mesh, st_b, self.cfg.n_ranks)
+            if self.cfg.fleet:
+                traj = self._rollout(self._replicate(params), st_b, obs_b,
+                                     self._replicate(key))
+                with spans.span("sync"):
+                    traj = _host_traj(self._gather(traj))
+            else:
+                traj = self._rollout(params, st_b, obs_b, key)
+            batch = self.postprocess(params, traj)
         if record:
             self._sink_write(self.episode, traj)
         self.episode += 1
@@ -723,18 +706,7 @@ class RolloutEngine:
                               key, step)
 
         kw = {"donate_argnums": (1,)} if donate and self.cfg.donate else {}
-        jitted = jax.jit(update, **kw)
-        if not self.cfg.timing:
-            return jitted
-
-        def timed(params, opt_state, batch, key, step):
-            t0 = time.perf_counter()
-            out = jitted(params, opt_state, batch, key, step)
-            jax.block_until_ready(out[0])
-            self.stats["update_s"] += time.perf_counter() - t0
-            return out
-
-        return timed
+        return jax.jit(update, **kw)
 
     # -- training loops ------------------------------------------------------
 
@@ -754,17 +726,22 @@ class RolloutEngine:
         step = jnp.int32(0) if step is None else jnp.asarray(step, jnp.int32)
         returns = []
         for _ in range(episodes):
-            key, kr, ku = jax.random.split(key, 3)
-            batch, traj = self.collect(params, st_b, obs_b, kr)
-            if on_batch is not None:   # e.g. the CFD<->DRL file interface
-                batch = on_batch(batch)
-            params, opt_state, step, metrics = update(params, opt_state,
-                                                      batch, ku, step)
-            returns.append(float(jnp.mean(jnp.sum(traj.reward, axis=1))))
-            if on_episode is not None:
-                on_episode(traj, metrics)
-            if on_state is not None:
-                on_state(TrainCarry(params, opt_state, step, key))
+            with spans.episode(self.episode):
+                with spans.span("collect"):
+                    key, kr, ku = jax.random.split(key, 3)
+                batch, traj = self.collect(params, st_b, obs_b, kr)
+                if on_batch is not None:   # e.g. the CFD<->DRL file interface
+                    batch = on_batch(batch)
+                with spans.span("update"):
+                    params, opt_state, step, metrics = update(
+                        params, opt_state, batch, ku, step)
+                with spans.span("sync"):
+                    returns.append(float(jnp.mean(jnp.sum(traj.reward,
+                                                          axis=1))))
+                if on_episode is not None:
+                    on_episode(traj, metrics)
+                if on_state is not None:
+                    on_state(TrainCarry(params, opt_state, step, key))
         return params, opt_state, np.asarray(returns)
 
     def replay_sync(self, reader, params, opt_state, ppo_cfg: PPOConfig,
@@ -786,18 +763,25 @@ class RolloutEngine:
         step = jnp.int32(0) if step is None else jnp.asarray(step, jnp.int32)
         returns = []
         for ep in range(start, start + episodes):
-            key, kr, ku = jax.random.split(key, 3)
-            del kr                      # run_sync's collect subkey, burned
-            traj = Trajectory(*(None if a is None else jnp.asarray(a)
-                                for a in reader.read(ep)))
-            batch = self.postprocess(params, traj)
-            if on_batch is not None:
-                batch = on_batch(batch)
-            params, opt_state, step, metrics = update(params, opt_state,
-                                                      batch, ku, step)
-            returns.append(float(jnp.mean(jnp.sum(traj.reward, axis=1))))
-            if on_state is not None:
-                on_state(TrainCarry(params, opt_state, step, key))
+            with spans.episode(ep):
+                with spans.span("io.sink"):
+                    recorded = reader.read(ep)
+                with spans.span("collect"):
+                    key, kr, ku = jax.random.split(key, 3)
+                    del kr              # run_sync's collect subkey, burned
+                    traj = Trajectory(*(None if a is None else jnp.asarray(a)
+                                        for a in recorded))
+                    batch = self.postprocess(params, traj)
+                if on_batch is not None:
+                    batch = on_batch(batch)
+                with spans.span("update"):
+                    params, opt_state, step, metrics = update(
+                        params, opt_state, batch, ku, step)
+                with spans.span("sync"):
+                    returns.append(float(jnp.mean(jnp.sum(traj.reward,
+                                                          axis=1))))
+                if on_state is not None:
+                    on_state(TrainCarry(params, opt_state, step, key))
         return params, opt_state, np.asarray(returns)
 
     def run_async(self, params, opt_state, ppo_cfg: PPOConfig, optimizer,
@@ -830,31 +814,40 @@ class RolloutEngine:
         spill = None                      # (episode, traj) awaiting the sink
         returns = []
         for i in range(episodes):
-            key, kr, ku = jax.random.split(key, 3)
             ep_id = self.episode
-            # both dispatches below can execute concurrently: collect uses
-            # the STALE params, and the update only touches the previous
-            # episode's batch — never the buffers collect is writing.
-            # The sink (host-blocking I/O) only ever sees the PREVIOUS,
-            # already-materialized episode, after the update is dispatched,
-            # so spilling never serializes the two in-flight programs.
-            batch, traj = self.collect(params, st_b, obs_b, kr, record=False)
-            if pending is not None:
-                params, opt_state, step, _ = update(params, opt_state,
-                                                    pending, ku, step)
-            if self.sink is not None and spill is not None:
-                self._sink_write(*spill)
-            pending = batch
-            spill = (ep_id, traj)
-            returns.append(float(jnp.mean(jnp.sum(traj.reward, axis=1))))
-            if on_episode is not None:
-                on_episode(traj, None)
-            if on_state is not None and (i + 1) % max(1, state_every) == 0:
-                on_state(TrainCarry(params, opt_state, step, key))
+            with spans.episode(ep_id):
+                with spans.span("collect"):
+                    key, kr, ku = jax.random.split(key, 3)
+                # both dispatches below can execute concurrently: collect
+                # uses the STALE params, and the update only touches the
+                # previous episode's batch — never the buffers collect is
+                # writing.  The sink (host-blocking I/O) only ever sees the
+                # PREVIOUS, already-materialized episode, after the update
+                # is dispatched, so spilling never serializes the two
+                # in-flight programs.
+                batch, traj = self.collect(params, st_b, obs_b, kr,
+                                           record=False)
+                if pending is not None:
+                    with spans.span("update"):
+                        params, opt_state, step, _ = update(
+                            params, opt_state, pending, ku, step)
+                if self.sink is not None and spill is not None:
+                    self._sink_write(*spill)
+                pending = batch
+                spill = (ep_id, traj)
+                with spans.span("sync"):
+                    returns.append(float(jnp.mean(jnp.sum(traj.reward,
+                                                          axis=1))))
+                if on_episode is not None:
+                    on_episode(traj, None)
+                if on_state is not None and (i + 1) % max(1,
+                                                          state_every) == 0:
+                    on_state(TrainCarry(params, opt_state, step, key))
         if drain and pending is not None:
             key, ku = jax.random.split(key)
-            params, opt_state, step, _ = update(params, opt_state, pending,
-                                                ku, step)
+            with spans.span("update"):
+                params, opt_state, step, _ = update(params, opt_state,
+                                                    pending, ku, step)
         if self.sink is not None and spill is not None:
             self._sink_write(*spill)
         if on_state is not None and episodes > 0:

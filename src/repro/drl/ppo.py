@@ -47,6 +47,7 @@ def make_optimizer(cfg: PPOConfig):
     return adamw(cfg.lr, max_grad_norm=cfg.max_grad_norm)
 
 
+@jax.named_scope("ppo_loss")
 def ppo_loss(cfg: PPOConfig, params, batch: Batch):
     """Clipped-surrogate loss.  When the batch carries a sentinel validity
     mask, the loss is computed BOTH with the historical unmasked reductions
@@ -111,37 +112,39 @@ def ppo_update(cfg: PPOConfig, optimizer, params, opt_state, batch: Batch,
             params, opt_state, step = carry
             sl = jax.tree.map(
                 lambda x: jax.lax.dynamic_slice_in_dim(x, i * mb, mb), shuffled)
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda p: ppo_loss(cfg, p, sl), has_aux=True)(params)
+            with jax.named_scope("ppo_grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    lambda p: ppo_loss(cfg, p, sl), has_aux=True)(params)
             fz = faults.active("grad_nan")
             if fz is not None:   # trace-time gate: absent in production traces
                 hit = step == int(fz.get("step", 0))
                 bad = jnp.where(hit, jnp.float32(jnp.nan), jnp.float32(0.0))
                 grads = jax.tree.map(lambda g: g + bad, grads)
-            if cfg.skip_nonfinite_grads:
-                # reject the whole update when the gradient is non-finite:
-                # params/opt_state keep their pre-update values and the skip
-                # is counted.  ``where(True, new, old)`` passes ``new``
-                # through exactly, so finite updates stay bitwise-identical
-                # to the unguarded program.  ``step`` advances either way —
-                # it indexes the schedule, not the applied-update count.
-                gnorm = global_norm(grads)
-                ok = jnp.isfinite(gnorm)
-                new_p, new_o = optimizer.update(grads, opt_state, params,
-                                                step)
-                sel = lambda n_, o_: jnp.where(ok, n_, o_)    # noqa: E731
-                params = jax.tree.map(sel, new_p, params)
-                opt_state = jax.tree.map(sel, new_o, opt_state)
-                # grad_norm reports APPLIED updates (0 when skipped): the
-                # rejected gradient is a handled fault, counted in
-                # grad_skips — it must not read as a live anomaly to the
-                # training watchdog
-                metrics = dict(metrics,
-                               grad_norm=jnp.where(ok, gnorm, 0.0),
-                               grad_skips=1.0 - ok.astype(jnp.float32))
-            else:
-                params, opt_state = optimizer.update(grads, opt_state,
-                                                     params, step)
+            with jax.named_scope("optimizer"):
+                if cfg.skip_nonfinite_grads:
+                    # reject the whole update when the gradient is non-finite:
+                    # params/opt_state keep their pre-update values and the skip
+                    # is counted.  ``where(True, new, old)`` passes ``new``
+                    # through exactly, so finite updates stay bitwise-identical
+                    # to the unguarded program.  ``step`` advances either way —
+                    # it indexes the schedule, not the applied-update count.
+                    gnorm = global_norm(grads)
+                    ok = jnp.isfinite(gnorm)
+                    new_p, new_o = optimizer.update(grads, opt_state, params,
+                                                    step)
+                    sel = lambda n_, o_: jnp.where(ok, n_, o_)    # noqa: E731
+                    params = jax.tree.map(sel, new_p, params)
+                    opt_state = jax.tree.map(sel, new_o, opt_state)
+                    # grad_norm reports APPLIED updates (0 when skipped): the
+                    # rejected gradient is a handled fault, counted in
+                    # grad_skips — it must not read as a live anomaly to the
+                    # training watchdog
+                    metrics = dict(metrics,
+                                   grad_norm=jnp.where(ok, gnorm, 0.0),
+                                   grad_skips=1.0 - ok.astype(jnp.float32))
+                else:
+                    params, opt_state = optimizer.update(grads, opt_state,
+                                                         params, step)
             return (params, opt_state, step + 1), metrics
 
         (params, opt_state, step), metrics = jax.lax.scan(

@@ -38,7 +38,8 @@ def rollout_episode(env_step_fn, params, st0, obs0, key, length: int,
 
     def step(carry, k):
         st, obs = carry
-        act, logp = networks.sample_action(params, obs, k, aux=aux0)
+        with jax.named_scope("policy"):
+            act, logp = networks.sample_action(params, obs, k, aux=aux0)
         # scalar envs take the bare amplitude (the historical program);
         # vector (multi-body) envs take the whole action vector
         a = act[0] if act.shape[0] == 1 else act

@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.cfd.env import CylinderEnv, EnvConfig
 from repro.ckpt import checkpoint as ckpt_mod
-from repro.drl import networks
+from repro.drl import networks, spans
 from repro.drl import train_state as ts_mod
 from repro.drl.engine import (EngineConfig, RolloutEngine, SinkSpec,
                               TrajectorySink, broadcast_env_state,
@@ -277,13 +277,19 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
 
     def on_batch(batch):
         # paper's CFD<->DRL interface experiment
-        return interface.exchange(batch) if interface is not None else batch
+        if interface is None:
+            return batch
+        with spans.span("io.interface"):
+            return interface.exchange(batch)
 
     def on_episode(traj, metrics):
         ep = len(hist["reward"])
-        r = float(jnp.mean(jnp.sum(traj.reward, axis=1)))
-        cd = float(jnp.mean(traj.cd[:, -10:]))
-        cl = float(jnp.mean(jnp.abs(traj.cl[:, -10:])))
+        with spans.span("sync"):
+            r = float(jnp.mean(jnp.sum(traj.reward, axis=1)))
+        with spans.span("sync"):
+            cd = float(jnp.mean(traj.cd[:, -10:]))
+        with spans.span("sync"):
+            cl = float(jnp.mean(jnp.abs(traj.cl[:, -10:])))
         hist["reward"].append(r)
         hist["cd"].append(cd)
         hist["cl"].append(cl)
@@ -292,10 +298,13 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
         t_ep[0] = now
         # self-healing counters: quarantined env-steps from the sentinel
         # mask, rejected updates from the learner guard
-        quar = (0.0 if traj.valid is None
-                else float(jnp.sum(1.0 - traj.valid)))
-        skips = 0.0 if metrics is None else float(metrics.get("grad_skips",
-                                                              0.0))
+        quar = 0.0
+        if traj.valid is not None:
+            with spans.span("sync"):
+                quar = float(jnp.sum(1.0 - traj.valid))
+        skips = 0.0
+        if metrics is not None and "grad_skips" in metrics:
+            skips = spans.read(metrics["grad_skips"])
         hist["quarantines"].append(quar)
         hist["grad_skips"].append(skips)
         if log_fn and (quar or skips):
@@ -306,10 +315,11 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
             log_fn(f"ep {ep:4d}  return {r:+8.3f}  CD(tail) {cd:.3f}  "
                    f"|CL| {cl:.3f}  {hist['wall'][-1]:.1f}s")
         if ep_hook is not None:
-            ep_hook(traj, metrics)
+            with spans.span("caller"):
+                ep_hook(traj, metrics)
         if watchdog is not None:
             mf = (None if metrics is None
-                  else {k: float(v) for k, v in metrics.items()})
+                  else {k: spans.read(v) for k, v in metrics.items()})
             reason = watchdog.observe(mf, episode=ep)
             if reason is not None:
                 # raised BEFORE on_state fires for this episode, so the
@@ -323,16 +333,17 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
         done = len(hist["reward"])    # episodes completed, incl. resumed
         if done % max(1, cfg.ckpt_every) and done != cfg.episodes:
             return
-        snap = TrainState(params=carry.params, opt_state=carry.opt_state,
-                          key=carry.key, step=carry.step,
-                          episode=jnp.int32(done),
-                          env_state=st_host if fleet else st_b,
-                          obs=obs_host if fleet else obs_b,
-                          history={f: np.asarray(hist[f])
-                                   for f in HISTORY_FIELDS})
-        ckpter.save(done, ts_mod.to_tree(snap),
-                    metadata=ts_mod.state_metadata(
-                        snap, {**run_meta, "health": fill_health()}))
+        with spans.span("io.ckpt"):
+            snap = TrainState(params=carry.params, opt_state=carry.opt_state,
+                              key=carry.key, step=carry.step,
+                              episode=jnp.int32(done),
+                              env_state=st_host if fleet else st_b,
+                              obs=obs_host if fleet else obs_b,
+                              history={f: np.asarray(hist[f])
+                                       for f in HISTORY_FIELDS})
+            ckpter.save(done, ts_mod.to_tree(snap),
+                        metadata=ts_mod.state_metadata(
+                            snap, {**run_meta, "health": fill_health()}))
 
     divergence: Optional[DivergenceError] = None
     try:

@@ -122,7 +122,8 @@ def fused_step(cfg: GridConfig, ga, u, v, red, black, jet_vel, re, act_mode,
         jax.ShapeDtypeStruct((1, 1), f32),
         jax.ShapeDtypeStruct((1, 1), f32),
     ]
-    outs = pl.pallas_call(kern, out_shape=out_shape, interpret=interpret)(
+    outs = pl.pallas_call(kern, out_shape=out_shape, interpret=interpret,
+                          name="actuation_fused_dt")(
         u, v, red, black, *geom,
         scalar(jet_vel), scalar(re), scalar(act_mode))
     u2, v2, red2, black2, cd, cl = outs

@@ -110,6 +110,7 @@ def select_tier(cfg: GridConfig) -> str:
 # the fused per-dt body (shared by the jnp tier and the Pallas kernel)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("poisson")
 def packed_projection_planes(cfg: GridConfig, red, black, rhs_r, rhs_b):
     """The pressure solve of one dt entirely on packed planes: the same
     omega schedule as ``poisson.solve`` (``polish`` trailing sweeps run

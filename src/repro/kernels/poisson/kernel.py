@@ -84,6 +84,7 @@ def rb_sor_slabs(p, rhs, *, dx: float, dy: float, omega: float,
         out_specs=slab,
         out_shape=jax.ShapeDtypeStruct((ny, nx), p.dtype),
         interpret=interpret,
+        name="poisson_rb_sor_slabs",
     )(p, p, p, rhs)
 
 
@@ -172,4 +173,5 @@ def rb_sor_slabs_packed(red, black, rhs_r, rhs_b, *, dx: float, dy: float,
         out_specs=[slab, slab],
         out_shape=[plane, plane],
         interpret=interpret,
+        name="poisson_rb_sor_packed",
     )(red, red, red, black, black, black, rhs_r, rhs_b)

@@ -311,12 +311,3 @@ def test_sink_read_errors_are_actionable(tmp_path):
     assert str(tmp_path) in msg and "codec" in msg and "episode 99" in msg
     fs.cleanup()
 
-
-def test_engine_timing_stats():
-    engine = RolloutEngine(_toy_step,
-                           EngineConfig(n_envs=N, horizon=T, timing=True))
-    params, optimizer, opt_state, key = engine.init(PCFG, PPO, seed=0)
-    st0 = jnp.ones((N, 3)) * 2.0
-    engine.run_sync(params, opt_state, PPO, optimizer, st0, st0, key, 2)
-    assert engine.stats["episodes"] == 2
-    assert engine.stats["collect_s"] > 0 and engine.stats["update_s"] > 0

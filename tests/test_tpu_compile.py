@@ -10,6 +10,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -72,8 +74,10 @@ def test_poisson_slab_kernel_compiles_vmapped(one_chip, res):
     fn = jax.vmap(lambda r, b, rr, rb: poisson_ops.rb_sor_planes(
         r, b, rr, rb, g.dx, g.dy, iters=40, omega=g.poisson_omega,
         interpret=False))
-    compiled = jax.jit(fn).lower(plane, plane, plane, plane).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(plane, plane, plane, plane).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's stable name is the instruction's, which the trace shows
+    assert re.search(r"%\w*poisson_rb_sor_packed\w*\.\d+ = ", text)
 
 
 def test_actuation_megakernel_compiles_vmapped(one_chip):
@@ -94,7 +98,9 @@ def test_actuation_megakernel_compiles_vmapped(one_chip):
         geom, batch(g.ny, g.nx + 1), batch(g.ny + 1, g.nx),
         batch(g.ny, g.nx // 2), batch(g.ny, g.nx // 2),
         batch(), batch(), batch()).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%\w*actuation_fused_dt\w*\.\d+ = ", text)
 
 
 @pytest.mark.parametrize("backend", ["pallas", "fused"])

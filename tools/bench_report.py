@@ -6,9 +6,8 @@ Every benchmark that measures something durable writes an
 ...).  This tool collects them into ``artifacts/BENCH_summary.json`` — one
 flat record per artifact with its schema tag and every scalar it contains
 (nested keys dotted) — plus a human-readable ``BENCH_summary.md`` dashboard:
-headline throughput/phase-share numbers, the projected parallel efficiency
-against the paper's measured 78% / 47x at 60 cores, and the golden-physics
-drift (Strouhal / C_D / C_L vs the checked-in reference).  The perf
+headline throughput numbers, the measured fleet parallel efficiency beside
+the paper's 78% / 47x at 60 cores, and the golden-physics drift (Strouhal / C_D / C_L vs the checked-in reference).  The perf
 trajectory across PRs is a single diffable file, and CI can upload the lot
 as workflow artifacts.
 
@@ -44,10 +43,7 @@ HEADLINES = (
     ("env_steps_per_s", "{:.1f}"),
     ("gate.speedup_vs_baseline", "{:.2f}x"),
     ("gate.passed", "{}"),
-    ("shares.collect", "{:.1%}"),
-    ("shares.update", "{:.1%}"),
     ("shares.sink_write", "{:.1%}"),
-    ("scaling_projection.projected_efficiency_60", "{:.1%}"),
     ("speedup_packed_vs_full", "{:.2f}x"),
     ("gate.measured_efficiency", "{:.1%}"),
     ("plan.n_envs", "{}"),
@@ -148,20 +144,15 @@ def render_markdown(summary: dict) -> str:
                      f"{', '.join(cells) or f'{len(scalars)} scalars'} |")
 
     train = next((e["scalars"] for n, e in summary["entries"].items()
-                  if e.get("schema") == "repro.bench_train/v1"), None)
+                  if e.get("schema", "").startswith("repro.bench_train/")),
+                 None)
     lines += ["", "## Paper targets (arXiv 2402.11515)", ""]
-    eff = (train or {}).get("scaling_projection.projected_efficiency_60")
-    spd = (train or {}).get("scaling_projection.projected_speedup_60")
     lines.append(f"- parallel efficiency @ 60 cores: paper "
                  f"{PAPER_TARGETS['efficiency_60cores']:.0%} "
-                 f"({PAPER_TARGETS['speedup_60cores']:.0f}x) | projected "
-                 + (f"from this host's phase split: {eff:.1%} ({spd:.1f}x)"
-                    if eff is not None else "from this host: not measured "
-                    "(run benchmarks/bench_train.py)"))
-    if train:
-        for k in ("shares.collect", "shares.update", "shares.sink_write"):
-            if k in train:
-                lines.append(f"- {k}: {train[k]:.1%}")
+                 f"({PAPER_TARGETS['speedup_60cores']:.0f}x); measured "
+                 f"across processes in the fleet section below")
+    if train and "shares.sink_write" in train:
+        lines.append(f"- shares.sink_write: {train['shares.sink_write']:.1%}")
 
     mega = next((e["scalars"] for n, e in summary["entries"].items()
                  if e.get("schema", "").startswith("repro.bench_megakernel/")),

@@ -29,9 +29,6 @@ Machine-readable lines on stdout (tests/bench parse these):
                                  --mode bench; train health counters —
                                  quarantines / grad_skips / rollbacks /
                                  sink_retries — in --mode train)
-    FLEET_TIMING process=<p> rollout_s=<s> gather_s=<s>
-                                (--mode bench with REPRO_FLEET_TIMING=1:
-                                 per-process rollout/gather wall split)
     FLEET_DONE episodes=<E>     (supervisor, after the fleet finishes)
 """
 import argparse
@@ -199,8 +196,6 @@ def run_runner_bench(args, cfg, info, on_episode) -> None:
         engine.rollout_local(params, st_b, obs_b, kw)   # warmup: compile
     else:
         engine.collect(params, st_b, obs_b, kw)         # warmup: compile
-    engine.stats.pop("rollout_s", None)
-    engine.stats.pop("gather_s", None)
     t0 = time.perf_counter()
     for _ in range(args.measure_episodes):
         key, kr = jax.random.split(key)
@@ -212,11 +207,6 @@ def run_runner_bench(args, cfg, info, on_episode) -> None:
             on_episode(traj, None)
             jax.block_until_ready(batch)
     elapsed = time.perf_counter() - t0
-    if os.environ.get("REPRO_FLEET_TIMING"):
-        print(f"FLEET_TIMING process={info.process_id} "
-              f"rollout_s={engine.stats.get('rollout_s', 0.0):.4f} "
-              f"gather_s={engine.stats.get('gather_s', 0.0):.4f}",
-              flush=True)
     env_steps = (args.measure_episodes * cfg.n_envs
                  * cfg.env.actions_per_episode * cfg.env.steps_per_action)
     if info.is_coordinator:

@@ -11,7 +11,9 @@ its result lines, and the first failure ends the run with a non-zero exit:
 2. golden: restart from ``tests/golden/cyl_re100_res8.npz`` and hold the
    Strouhal number, mean C_D and C_L amplitude to the golden test's
    tolerances, once per backend TPU code can choose on one chip:
-   ``reference`` (packed XLA sweep), ``pallas`` (slab kernel) and ``fused``
+   ``reference`` (packed XLA sweep at one env; first, the batched solve
+   kernel that serves vmapped env batches must reproduce that sweep bit
+   for bit over one 60-env solve), ``pallas`` (slab kernel) and ``fused``
    (the actuation megakernel).
 3. train: ``train()`` at the paper's deployment (``cyl_re100``, jets,
    ring149 probes, 60 envs, the 2x512 MLP, the grid and PPO settings of
@@ -106,6 +108,39 @@ def golden_window(label, backend, *, mesh=None, against=None):
     print(f"golden[{label}] ok: {n} dt in {wall:.2f} s (smoke timing, "
           f"compile included)")
     return stats, cds
+
+
+def batched_solve_exact_on_chip():
+    """On TPU the ``reference`` solve runs ``rb_sor_batched`` over the whole
+    env batch; over one solve of the paper deployment's 60-env batch it
+    must reproduce the XLA loop it replaced bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from repro.cfd import poisson
+    from repro.cfd.grid import GridConfig
+    from repro.kernels.poisson import ops
+    g = GridConfig(res=8, dt=0.01, poisson_iters=50)
+    polish = 10
+    check(ops.batched_kernel_fits(ops.kernel_platform(), g.ny, g.nx, N_ENVS),
+          "batched sor: the paper deployment's batch does not take the "
+          "kernel")
+    keys = jax.random.split(jax.random.PRNGKey(SEED), 4)
+    planes = [jax.random.normal(k, (N_ENVS, g.ny, g.nx // 2)) for k in keys]
+    kern = jax.jit(jax.vmap(lambda *p: ops.rb_sor_solve(
+        *p, dx=g.dx, dy=g.dy, omega=g.poisson_omega, iters=g.poisson_iters,
+        polish=polish)))
+    loop = jax.jit(jax.vmap(lambda *p: poisson.packed_sor_loop(
+        *p, g.poisson_omega, dx=g.dx, dy=g.dy, iters=g.poisson_iters,
+        n_sor=g.poisson_iters - min(polish, g.poisson_iters // 2))))
+    check("poisson_rb_sor_batched" in kern.lower(*planes).compile().as_text(),
+          "batched sor: the compiled solve holds no kernel call")
+    got, want = kern(*planes), loop(*planes)
+    gap = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, want))
+    ndiff = sum(int(jnp.sum(a != b)) for a, b in zip(got, want))
+    print(f"batched sor: kernel vs XLA loop over one solve of {N_ENVS} envs: "
+          f"largest gap {gap!r}, {ndiff} elements differ")
+    check(ndiff == 0, f"batched sor: {ndiff} elements differ from the XLA "
+                      f"loop (largest gap {gap!r})")
 
 
 def pack_exact_on_chip():
@@ -204,6 +239,7 @@ def train_run(label, cfg, *, want_devices=None):
 # ---------------------------------------------------------------------------
 
 def one_chip() -> None:
+    batched_solve_exact_on_chip()
     golden_window("reference", "reference")
     with kernel_phase():
         golden_window("pallas", "pallas")

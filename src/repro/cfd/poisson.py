@@ -10,7 +10,14 @@ over the interchangeable backends:
   "packed"     packed-checkerboard storage: red and black points held as two
                (ny, nx//2) planes so every sweep touches exactly the points
                it updates — no masks, no wasted update, ~half the FLOPs and
-               memory traffic of the full-grid sweep.  Even nx only.
+               memory traffic of the full-grid sweep.  Even nx only.  On a
+               TPU, with a static omega, the whole solve runs as one
+               kernels/poisson ``rb_sor_batched`` call whose block holds
+               every env of a vmapped batch (bit for bit the XLA loop
+               ``packed_sor_loop``), wherever that batch has two envs or
+               more and fits in VMEM
+               (``kernels.poisson.ops.batched_kernel_fits``); elsewhere the
+               XLA loop runs
   "full"       the original full-grid masked sweep — the oracle the packed
                layout is tested against
   "pallas"     kernels/poisson's TPU slab smoother (block-Jacobi slabs,
@@ -180,6 +187,22 @@ def packed_sweep_pair(red, black, rhs_r, rhs_b, om, *, dx, dy, row_odd):
     return red, black
 
 
+def packed_sor_loop(red, black, rhs_r, rhs_b, omega, *, dx, dy, iters: int,
+                    n_sor: int):
+    """``iters`` red+black pairs on packed (ny, W) planes: omega for the
+    first ``n_sor``, 1 (plain Gauss-Seidel) for the rest.  The XLA form of
+    the packed solve; ``kernels.poisson.rb_sor_batched`` matches it bit for
+    bit on the TPU."""
+    ny = red.shape[0]
+    row_odd = (jnp.arange(ny) % 2 == 1)[:, None]
+
+    def body(i, planes):
+        om = jnp.where(i < n_sor, omega, 1.0)
+        return packed_sweep_pair(*planes, rhs_r, rhs_b, om,
+                                 dx=dx, dy=dy, row_odd=row_odd)
+    return jax.lax.fori_loop(0, iters, body, (red, black))
+
+
 # ---------------------------------------------------------------------------
 # solve
 # ---------------------------------------------------------------------------
@@ -216,26 +239,30 @@ def _solve_impl(rhs, p0, omega_t, dx, dy, *, iters: int, omega_s, backend: str,
     if backend in ("packed", "pallas"):
         rhs_r, rhs_b = pack_checkerboard(rhs)
         red, black = pack_checkerboard(p)
-        row_odd = (jnp.arange(ny) % 2 == 1)[:, None]
+        from repro.kernels.poisson import ops as poisson_ops
 
         if backend == "pallas":
-            from repro.kernels.poisson import ops as poisson_ops
             red, black = poisson_ops.rb_sor_planes(red, black, rhs_r, rhs_b,
                                                    dx, dy, iters=n_sor,
                                                    omega=omega_s)
-            for_polish = n_polish
-        else:
-            def body(i, planes):
-                om = jnp.where(i < n_sor, omega, 1.0)
-                return packed_sweep_pair(*planes, rhs_r, rhs_b, om,
-                                         dx=dx, dy=dy, row_odd=row_odd)
-            red, black = jax.lax.fori_loop(0, iters, body, (red, black))
-            for_polish = 0
+            row_odd = (jnp.arange(ny) % 2 == 1)[:, None]
 
-        def gs(_, planes):
-            return packed_sweep_pair(*planes, rhs_r, rhs_b, 1.0,
-                                     dx=dx, dy=dy, row_odd=row_odd)
-        red, black = jax.lax.fori_loop(0, for_polish, gs, (red, black))
+            def gs(_, planes):
+                return packed_sweep_pair(*planes, rhs_r, rhs_b, 1.0,
+                                         dx=dx, dy=dy, row_odd=row_odd)
+            red, black = jax.lax.fori_loop(0, n_polish, gs, (red, black))
+        elif omega_t is None and poisson_ops.batched_kernel_fits(
+                poisson_ops.kernel_platform(), ny, nx, 2):
+            # the whole solve in one kernel call over the vmapped env batch;
+            # a batch the kernel does not serve (one env, or too many for
+            # VMEM) runs packed_sor_loop
+            red, black = poisson_ops.rb_sor_solve(
+                red, black, rhs_r, rhs_b, dx=dx, dy=dy, omega=omega_s,
+                iters=iters, polish=polish)
+        else:
+            red, black = packed_sor_loop(red, black, rhs_r, rhs_b, omega,
+                                         dx=dx, dy=dy, iters=iters,
+                                         n_sor=n_sor)
         return unpack_checkerboard(red, black)
 
     # backend == "full": the original masked full-grid sweep (the oracle)

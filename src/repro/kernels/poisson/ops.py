@@ -7,6 +7,10 @@ keeping the original full-grid slab kernel for comparison.  ``rb_sor_planes``
 is the plane-level loop ``cfd.poisson.solve`` composes with its packed
 polish sweeps, so the pallas backend never round-trips through the full-grid
 layout mid-solve.
+
+``rb_sor_solve`` is the packed solve of one env that ``cfd.poisson.solve``
+runs on TPU: a ``custom_vmap`` hands ``kernel.rb_sor_batched`` the whole
+vmapped env batch in one block, where ``batched_kernel_fits`` says it fits.
 """
 from __future__ import annotations
 
@@ -15,11 +19,77 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.poisson.kernel import rb_sor_slabs, rb_sor_slabs_packed
+from repro.kernels.poisson.kernel import (rb_sor_batched, rb_sor_slabs,
+                                          rb_sor_slabs_packed)
+
+# Mosaic's default scoped VMEM limit on the TPU v5e
+VMEM_SCOPED_LIMIT = 16 * 2 ** 20
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def kernel_platform() -> str:
+    """The platform the batched kernel's dispatch rule is asked about."""
+    return jax.default_backend()
+
+
+def batched_kernel_fits(platform: str, ny: int, nx: int, batch: int) -> bool:
+    """Whether ``rb_sor_batched`` serves a packed solve of ``batch`` envs on
+    an (ny, nx) float32 grid: on the TPU, for an even nx and a batch of two
+    envs or more, when the kernel's six (ny, batch, nx/2) VMEM planes (four
+    in, two out) fit under the scoped limit.  VMEM tiles pad the envs to
+    whole groups of 8 sublanes and the packed columns to whole groups of
+    128 lanes, so the footprint only grows with the batch.  One env keeps
+    the XLA loop: there the kernel saves about 20 µs per solve, less than
+    its trace and lowering cost in each single-env program (the flow
+    warm-up, a lone ``solver.step``)."""
+    if platform != "tpu" or nx % 2 or batch < 2:
+        return False
+    sublanes, lanes = -(-batch // 8) * 8, -(-(nx // 2) // 128) * 128
+    return 6 * ny * sublanes * lanes * 4 <= VMEM_SCOPED_LIMIT
+
+
+@functools.lru_cache(maxsize=None)
+def _batched_solve(dx: float, dy: float, omega: float, iters: int,
+                   polish: int):
+    """The packed solve for planes (B, ny, W), as a ``custom_vmap`` whose
+    rule folds every vmapped axis into B, so the kernel's block holds the
+    whole env batch however many ``vmap`` levels built it."""
+    from repro.cfd import poisson
+    n_sor = iters - min(polish, iters // 2)
+
+    @jax.custom_batching.custom_vmap
+    def solve(red, black, rhs_r, rhs_b):
+        batch, ny, w = red.shape
+        if batched_kernel_fits(kernel_platform(), ny, 2 * w, batch):
+            return rb_sor_batched(red, black, rhs_r, rhs_b, dx=dx, dy=dy,
+                                  omega=omega, iters=iters, polish=polish,
+                                  interpret=not _on_tpu())
+        loop = functools.partial(poisson.packed_sor_loop, omega=omega, dx=dx,
+                                 dy=dy, iters=iters, n_sor=n_sor)
+        return jax.vmap(loop)(red, black, rhs_r, rhs_b)
+
+    @solve.def_vmap
+    def _rule(axis_size, in_batched, *planes):
+        planes = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                  for a, b in zip(planes, in_batched)]
+        out = solve(*(a.reshape((-1,) + a.shape[2:]) for a in planes))
+        return (tuple(a.reshape((axis_size, -1) + a.shape[1:]) for a in out),
+                (True, True))
+
+    return solve
+
+
+def rb_sor_solve(red, black, rhs_r, rhs_b, *, dx: float, dy: float,
+                 omega: float, iters: int, polish: int):
+    """``cfd.poisson.packed_sor_loop``'s solve of one env's (ny, W) planes,
+    run by ``rb_sor_batched`` over the whole batch when this is vmapped and
+    ``batched_kernel_fits`` takes the batch, else by the XLA loop."""
+    solve = _batched_solve(float(dx), float(dy), float(omega), iters, polish)
+    red, black = solve(red[None], black[None], rhs_r[None], rhs_b[None])
+    return red[0], black[0]
 
 
 def _pick_nslabs(nx: int) -> int:

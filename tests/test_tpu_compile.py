@@ -80,6 +80,30 @@ def test_poisson_slab_kernel_compiles_vmapped(one_chip, res):
     assert re.search(r"%\w*poisson_rb_sor_packed\w*\.\d+ = ", text)
 
 
+@pytest.mark.parametrize("batch", [N_ENVS, 1])
+def test_batched_sor_kernel_compiles(one_chip, monkeypatch, batch):
+    """backend="reference" on TPU: the whole packed solve of the paper
+    deployment's grid in one call whose block holds every env, vmapped as
+    the rollout runs it; and the kernel alone at one env."""
+    from repro.kernels.poisson import kernel as poisson_kernel
+    monkeypatch.setattr(poisson_ops, "kernel_platform", lambda: "tpu")
+    monkeypatch.setattr(poisson_ops, "_on_tpu", lambda: True)
+    g = _grid(8)
+    kw = dict(dx=g.dx, dy=g.dy, omega=g.poisson_omega, iters=g.poisson_iters,
+              polish=10)
+    if batch > 1:
+        fn = jax.vmap(lambda *planes: poisson_ops.rb_sor_solve(*planes, **kw))
+    else:
+        fn = lambda *planes: poisson_kernel.rb_sor_batched(  # noqa: E731
+            *planes, interpret=False, **kw)
+    plane = _spec((batch, g.ny, g.nx // 2), one_chip)
+    text = jax.jit(fn).lower(plane, plane, plane, plane).compile().as_text()
+    # one call, its block: rows, then every env, then packed columns
+    block = rf"f32\[{g.ny},{batch},{g.nx // 2}\]"
+    assert len(re.findall(rf"%poisson_rb_sor_batched\.\d+ = \({block}",
+                          text)) == 1
+
+
 def test_actuation_megakernel_compiles_vmapped(one_chip):
     """backend="fused" on TPU: one dt of the megakernel over the env batch,
     at the paper deployment's grid."""

@@ -18,13 +18,22 @@ configuration states (``high``: three bf16 passes, ``default``: one); with
 - ``reward``      every reward is off by 0.01 where the env produces it;
 - ``batch_rows``  the learner's batch pairs each row's observation with the
                   action of the row before, where the batch is assembled;
+- ``policy_logp`` the rollout's sampling log-probability is computed with
+                  the log-std shifted by 0.01 (the action is drawn as
+                  before), a slip a fused policy kernel could make;
+- ``skip_leaf``   the PPO update leaves the policy's output layer weights
+                  (``SKIPPED``) as they were, and moves every other leaf
+                  and the optimizer state as before;
 - ``exchange``    the update reads only the first chip's share of the batch,
                   as each chip does when the gradients' reduction across
                   chips is left out (cells on more than one chip).
 
-Runs on the chip like ``bench/run.py``.  Each run's readings go to stdout
-as they come, then a summary: the largest reading of the sound runs
-(``lower``) and the smallest of each control and fault (``<group>_min``).
+Runs on the chip like ``bench/run.py``, through the harness's own run, so
+the state each run records comes through the same checkpoint stand-in as in
+a timed run.  Each run's readings go to stdout as they come, each with the
+episode (or leaf) where it was worst, then a summary: the largest reading of
+the sound runs (``lower``) and the smallest of each control and fault
+(``<group>_min``), each with the seed and place it came from.
 """
 import argparse
 import contextlib
@@ -35,17 +44,20 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
+SKIPPED = "['actor'][2]['w']"
 
 
 @contextlib.contextmanager
 def planted(fault: str):
     """Plant ``fault`` in the program for the duration of the block."""
     import jax
+    import jax.numpy as jnp
     from repro.cfd import env as env_mod
-    from repro.drl import engine
+    from repro.drl import engine, networks
     orig_update = engine.ppo_update
     orig_step = env_mod.CylinderEnv.env_step
     orig_batch = engine.Batch
+    orig_sample = networks.sample_action
 
     def unchanged(cfg, opt, params, opt_state, batch, key, step):
         _, _, new_step, metrics = orig_update(cfg, opt, params, opt_state,
@@ -55,6 +67,14 @@ def planted(fault: str):
     def half_batch(cfg, opt, params, opt_state, batch, key, step):
         half = jax.tree.map(lambda x: x[: x.shape[0] // 2], batch)
         return orig_update(cfg, opt, params, opt_state, half, key, step)
+
+    def skip_leaf(cfg, opt, params, opt_state, batch, key, step):
+        new, new_opt, new_step, metrics = orig_update(
+            cfg, opt, params, opt_state, batch, key, step)
+        new = jax.tree_util.tree_map_with_path(
+            lambda k, a, b: b if jax.tree_util.keystr(k) == SKIPPED else a,
+            new, params)
+        return new, new_opt, new_step, metrics
 
     def exchange(cfg, opt, params, opt_state, batch, key, step):
         n = jax.device_count()
@@ -66,19 +86,31 @@ def planted(fault: str):
         return st2, out._replace(reward=out.reward + 0.01)
 
     def batch_rows(**fields):
-        fields["act"] = jax.numpy.roll(fields["act"], 1, axis=0)
+        fields["act"] = jnp.roll(fields["act"], 1, axis=0)
         return orig_batch(**fields)
+
+    def policy_logp(params, obs, key, aux=None):
+        act, _ = orig_sample(params, obs, key, aux=aux)
+        mean, log_std = networks.policy_dist(params, obs, aux)
+        log_std = log_std + 0.01
+        logp = -0.5 * ((act - mean) ** 2 / jnp.exp(2 * log_std)
+                       + 2 * log_std + jnp.log(2 * jnp.pi))
+        return act, jnp.sum(logp, axis=-1)
 
     if fault == "unchanged":
         engine.ppo_update = unchanged
     elif fault == "half_batch":
         engine.ppo_update = half_batch
+    elif fault == "skip_leaf":
+        engine.ppo_update = skip_leaf
     elif fault == "exchange":
         engine.ppo_update = exchange
     elif fault == "reward":
         env_mod.CylinderEnv.env_step = reward
     elif fault == "batch_rows":
         engine.Batch = batch_rows
+    elif fault == "policy_logp":
+        networks.sample_action = policy_logp
     elif fault != "none":
         raise ValueError(f"unknown fault {fault!r}")
     try:
@@ -87,6 +119,7 @@ def planted(fault: str):
         engine.ppo_update = orig_update
         env_mod.CylinderEnv.env_step = orig_step
         engine.Batch = orig_batch
+        networks.sample_action = orig_sample
 
 
 def main(argv=None) -> int:
@@ -124,49 +157,60 @@ def main(argv=None) -> int:
                                    limits=limits,
                                    setup=setups.get(jnp.float32),
                                    keep_record=recs)
-        return res, recs[0]
+        return row(res["checks"], res["where"]), recs[0]
+
+    def row(checks, where):
+        """{name: [reading, where it was worst]}"""
+        return {k: [checks[k]["value"], where.get(k, "-")] for k in checks}
+
+    def show(label, r):
+        print(f"{label}: " + ", ".join(f"{k} {v!r} ({w})"
+                                       for k, (v, w) in r.items()),
+              flush=True)
 
     for seed in seeds:
         t0 = time.perf_counter()
-        res, rec = one(seed)
+        out["program"][seed], rec = one(seed)
         n_envs = rec["episodes"][0]["traj"]["act"].shape[0]
         for dt in (jnp.float32, jnp.bfloat16):
             if dt not in setups:
                 setups[dt] = replay.Setup(cfg, traffic, n_envs, dt)
-        out["program"][seed] = {k: v["value"] for k, v in res["checks"].items()}
+        show(f"seed {seed} program", out["program"][seed])
         if args.control:
             crec = replay.control(rec, cfg, traffic, seed,
                                   setup=setups[jnp.bfloat16])
             ref = replay.view(crec, cfg, traffic, seed,
                               setup=setups[jnp.float32])
-            out["control"].setdefault("reference_bfloat16", {})[seed] = (
-                check.numbers(crec, ref, traffic)[0])
-        print(f"seed {seed}: program {out['program'][seed]} control "
-              f"{out['control'].get('reference_bfloat16', {}).get(seed)} "
-              f"({time.perf_counter() - t0:.1f} s)", flush=True)
+            readings, where = check.numbers(crec, ref, traffic)
+            r = row(check.judge(readings, limits)[1], where)
+            out["control"].setdefault("reference_bfloat16", {})[seed] = r
+            show(f"seed {seed} control", r)
+        print(f"seed {seed}: {time.perf_counter() - t0:.1f} s", flush=True)
     for prec in filter(None, args.precisions.split(",")):
         rows = out["control"].setdefault(f"program_{prec}", {})
         for seed in seeds[:args.fault_seeds]:
-            res, _ = one(seed, precision=prec)
-            rows[seed] = {k: v["value"] for k, v in res["checks"].items()}
-            print(f"program at {prec} seed {seed}: {rows[seed]}", flush=True)
+            rows[seed], _ = one(seed, precision=prec)
+            show(f"program at {prec} seed {seed}", rows[seed])
     for fault in filter(None, args.faults.split(",")):
-        out["faults"][fault] = {}
+        rows = out["faults"].setdefault(fault, {})
         for seed in seeds[:args.fault_seeds]:
-            res, _ = one(seed, fault)
-            out["faults"][fault][seed] = {k: v["value"]
-                                          for k, v in res["checks"].items()}
-            print(f"fault {fault} seed {seed}: {out['faults'][fault][seed]}",
-                  flush=True)
-    summary = {"lower": {n: max(r[n] for r in out["program"].values())
+            rows[seed], _ = one(seed, fault)
+            show(f"fault {fault} seed {seed}", rows[seed])
+
+    def pick(rows, n, best):
+        """-> [reading, seed, where] of the run ``best`` picks for ``n``; a
+        reading that is no number fails but sets no upper end."""
+        cands = [[math.inf if v is None else v, seed, w]
+                 for seed, r in rows.items() for v, w in [r[n]]]
+        return best(cands, key=lambda c: c[0])
+
+    summary = {"lower": {n: pick(out["program"], n, max)
                          for n in check.NAMES}}
     for group, rows in {**out["control"], **out["faults"]}.items():
-        # a reading that is no number fails but sets no upper end
-        summary[f"{group}_min"] = {
-            n: min(r[n] if r[n] is not None and math.isfinite(r[n])
-                   else math.inf for r in rows.values())
-            for n in check.NAMES}
-    print(json.dumps(summary, indent=1))
+        summary[f"{group}_min"] = {n: pick(rows, n, min)
+                                   for n in check.NAMES}
+    for group, picks in summary.items():
+        print(f"{group}: {json.dumps(picks)}", flush=True)
     return 0
 
 

@@ -2,34 +2,58 @@
 
 A run records its first episodes (``traffic.check.episodes`` of them, plus
 one more whose log-probabilities and advantages show the weights after the
-last compared update), with the weights and the optimizer's first moment
-after each compared update.  One episode is one training step: a rollout of
-every env, then a PPO update.  The plain reference replays them
-(``bench.reference.replay.view``) and six numbers measure how far the run
-lies from it; each is held to its limit in ``bench/limits/<cell>.json``:
+last compared update), with its state after each compared update: the
+weights, Adam's two moments and the step count.  One episode is one
+training step: a rollout of every env, then a PPO update.  The plain
+reference replays them (``bench.reference.replay.view``) and six numbers
+measure how far the run lies from it; each is held to its limit in
+``bench/limits/<cell>.json``.  Each number says under whose state the
+reference reads it:
 
-- ``traj_gap``   the env step (solver, forces, probes, reward): the largest
-                 gap over obs, last obs, reward, C_D and C_L between the
-                 run's trajectory and the reference's under the same
-                 actions, each field against its own largest magnitude;
+- ``traj_gap``   the env step (solver, forces, probes, reward), under the
+                 recorded actions: the largest gap over obs, last obs,
+                 reward, C_D and C_L between the run's trajectory and the
+                 reference's, each field against its own largest magnitude;
                  and the batch the learner consumed (obs, act, logp_old,
-                 valid) against the run's trajectory it was made from,
-                 env by env in time order, where a copy reads 0.
-- ``logp_gap``   the policy as each update left it: the largest gap, in
-                 nats, between the log-probabilities of the recorded
-                 actions.
-- ``gae_gap``    values and GAE: the largest gap of advantages and returns,
-                 each against its own largest magnitude.
-- ``loss_gap``   each update's mean loss (clipped surrogate + value - entropy
-                 bonus), relative.
-- ``grad_gap``   the gradient as the optimizer gets it: Adam's first moment
+                 valid) against the run's trajectory it was made from, env
+                 by env in time order, where a copy reads 0.
+- ``logp_gap``   the policy in the rollout, under the program's weights
+                 that collected the episode (the reference's initial
+                 weights for the first, else the run's after the update
+                 before): the largest gap, in nats, of the recorded
+                 actions' log-probabilities.
+- ``gae_gap``    values and GAE, under the same weights: the largest gap of
+                 advantages and returns, each against its own largest
+                 magnitude.
+- ``loss_gap``   one PPO update, from the program's state before it (the
+                 initial state for the first) on the recorded batch: its
+                 mean loss (clipped surrogate + value - entropy bonus),
+                 relative.
+- ``grad_gap``   the gradient as the optimizer gets it, under the
+                 reference's own learner from the seed: Adam's first moment
                  after the first update, by the worst leaf (the gap between
                  the two norms of a leaf over the larger of the reference's
                  norm of that leaf and of the median leaf).
-- ``change_gap`` the weights' change over the compared updates, from the
-                 reference's initial weights, by the worst leaf as above;
-                 leaves whose reference gradient is under a thousandth of
-                 the median leaf's move by round-off alone and are left out.
+- ``change_gap`` the weights' change over the compared updates, the run's
+                 against the reference's own learner's from the seed, by
+                 the worst leaf as above; leaves whose reference gradient
+                 is under a thousandth of the median leaf's move by
+                 round-off alone and are left out.
+
+The four per-step numbers are teacher-forced: two float32 learners round
+differently, and a sample at the PPO clip edge turns that rounding into a
+different gradient, so after a few updates two sound learners hold
+different policies and a reading under the reference's own weights would
+measure that divergence, not the step.  Under the program's state each
+reading covers one step, and no rounding carries from one to the next.
+``grad_gap`` and ``change_gap`` are not teacher-forced: they guard against
+an update that is a little wrong every time, which no single step shows
+but three steps add up, and against a leaf the update leaves unmoved.  A
+sound run's worst leaf parts by up to 6% over three updates where a
+sample crosses the clip edge on one side only; a leaf left unmoved reads
+its change over the larger of that change and the median leaf's, so an
+unmoved leaf far smaller than the median (a bias, the log-std) stays under
+the limit.
 
 A quarantine the reference does not share (or the reverse) reads as an
 infinite ``traj_gap``.
@@ -62,7 +86,8 @@ def leaves(tree) -> dict:
 def leaf_gap(got: dict, ref: dict, keep=None) -> tuple:
     """-> (worst gap of leaf norms, its leaf).  Each leaf's gap is measured
     against the larger of the reference's norm of that leaf and of the
-    median leaf; a leaf missing on one side reads infinite."""
+    median leaf; a leaf missing on one side, or of another shape, reads
+    infinite.  Leaves of ``ref`` not in ``keep`` are left out."""
     norms = {k: float(np.linalg.norm(v)) for k, v in ref.items()}
     floor = float(np.median(list(norms.values())))
     worst, where = 0.0, "-"

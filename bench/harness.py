@@ -100,9 +100,10 @@ def _host(tree) -> dict:
 
 class Snapshots:
     """Stands in for ``train()``'s checkpoint writer during the compared
-    episodes: it keeps the weights and Adam's first moment that ``train()``
-    hands it after each of them, on the host, then turns checkpointing off
-    so that the window saves nothing.  Nothing is written to disk."""
+    episodes: it keeps the state that ``train()`` hands it after each of
+    them (the weights, Adam's two moments and the PPO step count), on the
+    host, then turns checkpointing off so that the window saves nothing.
+    Nothing is written to disk."""
 
     saves, bytes_written, time_blocked = 0, 0, 0.0
 
@@ -118,7 +119,9 @@ class Snapshots:
         from bench.check import leaves
         if step <= self.count:
             self.states[step] = {"params": leaves(tree["params"]),
-                                 "m": leaves(tree["opt_state"]["m"])}
+                                 "m": leaves(tree["opt_state"]["m"]),
+                                 "v": leaves(tree["opt_state"]["v"]),
+                                 "step": int(np.asarray(tree["step"]))}
         if step >= self.count:
             self.tcfg.ckpt_every = 10 ** 9
 
@@ -353,6 +356,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         log(f"check {name}: {c['value']!r} (limit {c['limit']!r}; "
             f"worst at {where.get(name, '-')})")
     result["correct"] = correct
+    result["where"] = where
     result["checks"] = checks
     return result
 

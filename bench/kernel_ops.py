@@ -1,16 +1,18 @@
-"""Runs of one named kernel inside the program's complete episodes.
+"""Runs of named device operations inside the program's complete episodes.
 
-A Pallas kernel's ``name`` becomes its HLO instruction's name
-(``poisson_rb_sor_batched.1``), which ``trace_reduce.op_name`` gives for
-each of its op events.  Only events that lie inside a complete
-``repro/episode`` span count (``bench/spans.complete_episodes``), so the
-warm-up episode that the trace starts in and the one it stops in add
-nothing.
+An operation's HLO instruction name (``poisson_rb_sor_batched.1``,
+``all-reduce.3``) is what ``trace_reduce.op_name`` gives for each of its op
+events; a Pallas kernel's ``name`` becomes that name.  Only events that lie
+inside a complete ``repro/episode`` span count
+(``bench/spans.complete_episodes``), so the warm-up episode that the trace
+starts in and the one it stops in add nothing.  Where a program is named,
+only events inside one of its runs on the same chip (its ``XLA Modules``
+events, ``jit_<program>``) count.
 """
 from __future__ import annotations
 
 import re
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 from bench import spans, trace_reduce
 
@@ -23,18 +25,28 @@ class KernelRuns(NamedTuple):
     seconds: float    # their summed durations per chip
 
 
-def runs(trace, kernel: str) -> Optional[KernelRuns]:
-    """The events of ``kernel`` inside complete episodes, averaged over
-    chips; ``None`` when there is no complete episode or no such event."""
+def _inside(e, spans_) -> bool:
+    return any(s.start <= e.start and e.end <= s.end for s in spans_)
+
+
+def runs(trace, match: Callable[[str], bool],
+         program: Optional[str] = None) -> Optional[KernelRuns]:
+    """The op events whose instruction name, its ``.N`` suffix dropped,
+    ``match`` accepts, inside complete episodes (and inside a run of
+    ``program`` when given), averaged over chips; ``None`` when there is no
+    complete episode or no such event."""
     eps = spans.complete_episodes(trace)
     if not eps:
         return None
     calls, ns = 0, 0
     for chip in trace.chips:
+        mods = None if program is None else [
+            m for m in chip.modules
+            if trace_reduce.module_program(m.name) == program]
         for e in chip.ops:
-            if SUFFIX.sub("", trace_reduce.op_name(e.name)) != kernel:
+            if not match(SUFFIX.sub("", trace_reduce.op_name(e.name))):
                 continue
-            if any(ep.start <= e.start and e.end <= ep.end for ep in eps):
+            if _inside(e, eps) and (mods is None or _inside(e, mods)):
                 calls += 1
                 ns += e.end - e.start
     if not calls:

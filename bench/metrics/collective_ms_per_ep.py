@@ -1,8 +1,14 @@
-"""Device time of collective operations per episode, averaged over chips."""
+"""Device time of the learner's collective operations (all-reduce,
+all-gather, ...) inside ``jit_update`` runs within complete episodes, per
+complete episode, averaged over chips (``bench/kernel_ops.py``): what the
+data-parallel update exchanges across chips."""
+from bench import kernel_ops, trace_reduce
+
+
+def _collective(name: str) -> bool:
+    return bool(trace_reduce.COLLECTIVE.search(name))
 
 
 def read(ctx):
-    t = ctx["trace"]
-    if not t.has_collectives():
-        return None
-    return 1e3 * t.collective_s() / ctx["episodes"]
+    r = kernel_ops.runs(ctx["trace"], _collective, program="update")
+    return None if r is None else 1e3 * r.seconds / r.episodes

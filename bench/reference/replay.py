@@ -1,12 +1,13 @@
 """The reference run of a cell's first episodes.
 
 ``view`` replays what a run recorded (the actions it took, the batches its
-learner consumed) through the plain reference and returns what the reference
-makes of the same inputs; ``control`` builds a whole record from the
-reference computed in bfloat16, the precision below the float32 the
-configuration states.  Both start from the seed alone: the reference draws
-its own initial weights, builds its own geometry and probe tables, and runs
-its own uncontrolled warm-up."""
+learner consumed, its state after each update) through the plain reference
+and returns what the reference makes of the same inputs; ``control`` builds
+a whole record from the reference computed in bfloat16, the precision below
+the float32 the configuration states.  Both start from the seed alone: the
+reference draws its own initial weights, builds its own geometry and probe
+tables, and runs its own uncontrolled warm-up.  The recorded state is only
+ever loaded as the input of one reference step, never taken as a result."""
 from __future__ import annotations
 
 import functools
@@ -173,37 +174,61 @@ class Learner:
         return {k: float(v) for k, v in metrics.items()}
 
     def state(self) -> dict:
-        """The weights and Adam's first moment, as float32 host leaves."""
+        """The weights, Adam's moments (float32 host leaves) and the step."""
         return {"params": check.leaves(self.params),
-                "m": check.leaves(self.opt["m"])}
+                "m": check.leaves(self.opt["m"]),
+                "v": check.leaves(self.opt["v"]), "step": int(self.step)}
+
+    def load(self, state: dict):
+        """Take a recorded state (``state()``'s form) as this learner's own;
+        it has to hold every leaf of the reference's tree."""
+        def tree(flat):
+            paths, treedef = jax.tree_util.tree_flatten_with_path(self.params)
+            return jax.tree_util.tree_unflatten(treedef, [
+                self._cast(flat[jax.tree_util.keystr(k)]) for k, _ in paths])
+        self.params = tree(state["params"])
+        self.opt = {"m": tree(state["m"]), "v": tree(state["v"])}
+        self.step = jnp.int32(state["step"])
 
 
 def view(record: dict, cfg: dict, traffic: dict, seed: int,
          dtype=jnp.float32, setup: Setup = None) -> list:
-    """The reference's reading of each recorded episode: its own trajectory
-    under the recorded actions (for the first ``check.episodes``), the
-    log-probabilities and advantages its policy gives the recorded steps,
-    and the metrics of its PPO update on the recorded batch."""
+    """The reference's reading of each recorded episode.
+
+    Read under the program's own state at that step (teacher-forced): the
+    log-probabilities of the recorded actions and the advantages and returns
+    of the recorded steps, under the weights that collected the episode
+    (the reference's initial weights for the first, else the run's weights
+    after the update before it); and the metrics of one PPO update on the
+    recorded batch from the run's state before that update.  Read by the
+    reference's own learner, run from the seed through the compared updates
+    on the recorded batches (free-running): its state after each of them.
+    For the first ``check.episodes`` also the reference's own trajectory
+    under the recorded actions."""
     eps = record["episodes"]
     n_env = traffic["check"]["episodes"]
     if setup is None:
         setup = Setup(cfg, traffic, eps[0]["traj"]["act"].shape[0], dtype)
-    lrn = Learner(cfg, traffic, setup, seed)
+    free = Learner(cfg, traffic, setup, seed)
+    forced = Learner(cfg, traffic, setup, seed)
     out = []
-    p0 = check.leaves(lrn.params)
+    p0 = check.leaves(free.params)
     for k, ep in enumerate(eps):
-        _, ku = lrn.next_keys()
+        _, ku = free.next_keys()
+        if k:
+            forced.load(eps[k - 1])
         traj = ep["traj"]
-        got = {"logp": np.asarray(lrn.logp(traj["obs"], traj["act"]),
+        got = {"logp": np.asarray(forced.logp(traj["obs"], traj["act"]),
                                   np.float32)}
-        adv, ret = lrn.advantages(traj)
+        adv, ret = forced.advantages(traj)
         got["adv"] = np.asarray(adv, np.float32).reshape(-1)
         got["ret"] = np.asarray(ret, np.float32).reshape(-1)
         if k < n_env:
             got["env"] = {f: np.asarray(v, np.float32)
                           for f, v in setup.episode(traj["act"]).items()}
-            got["metrics"] = lrn.update(ep["batch"], ku)
-            got.update(lrn.state())
+            got["metrics"] = forced.update(ep["batch"], ku)
+            free.update(ep["batch"], ku)
+            got.update(free.state())
         out.append(got)
     out[0]["p0"] = p0
     return out

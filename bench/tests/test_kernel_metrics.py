@@ -1,4 +1,4 @@
-"""The batched solve kernel's calls and device time per complete episode
+"""The pressure-solve kernels' calls and device time per complete episode
 (``bench/kernel_ops.py``), on hand-built traces."""
 import pytest
 
@@ -35,8 +35,14 @@ def _chip(index=0, scale=1.0):
     return tr.Chip(index, ops, [_ev("jit_collect_traj(1)", -6, 120)])
 
 
-def test_counts_and_times_inside_complete_episodes():
-    ctx = _ctx([_chip()], HOST)
+@pytest.mark.parametrize("kernel", ["batched", "packed"])
+def test_counts_and_times_inside_complete_episodes(kernel):
+    """``plan=None`` solves with ``poisson_rb_sor_batched``, the
+    data-parallel plan with ``poisson_rb_sor_packed``."""
+    chip = _chip()
+    chip = chip._replace(ops=[e._replace(name=e.name.replace(
+        "_batched", f"_{kernel}")) for e in chip.ops])
+    ctx = _ctx([chip], HOST)
     assert sor_kernel_calls_per_ep.read(ctx) == pytest.approx(3.0)
     assert sor_kernel_ms_per_ep.read(ctx) == pytest.approx(6.0)
 

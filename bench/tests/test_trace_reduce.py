@@ -37,19 +37,30 @@ def test_idle_share_and_gaps():
 
 
 def test_programs_and_collectives_by_name():
-    chip = _chip([("all-reduce.7", 0, 2_000_000),
-                  ("all-reduce-start.1", 5, 1_000_005),
-                  ("fusion.12", 0, 9_000_000),
-                  ("collective-permute-done", 1, 1_000_001)],
-                 [("jit_collect_traj(12)", 0, 30_000_000),
-                  ("jit_postprocess(3)", 0, 4_000_000),
-                  ("jit_update(9)", 0, 6_000_000),
-                  ("jit_update_other(1)", 0, 99_000_000)])
-    red = tr.Reduced([chip, chip._replace(index=1)], [])
+    """Program time by module name; the learner's collectives by instruction
+    name, only inside ``jit_update`` runs within complete episodes."""
+    ms = 1_000_000
+    chip = _chip([("all-reduce.7", 1 * ms, 3 * ms),
+                  ("all-reduce-start.1", 4 * ms, 5 * ms),
+                  ("fusion.12", 0, 9 * ms),
+                  ("%fusion.13 = f32[8]{0} fusion(%all-reduce.7)",
+                   6 * ms, 7 * ms),
+                  ("collective-permute-done", 11 * ms, 12 * ms),
+                  ("all-reduce.8", 42 * ms, 45 * ms)],
+                 [("jit_collect_traj(12)", 10 * ms, 40 * ms),
+                  ("jit_postprocess(3)", 0, 4 * ms),
+                  ("jit_update(9)", 0, 6 * ms),
+                  ("jit_update(9)", 41 * ms, 46 * ms),
+                  ("jit_update_other(1)", 0, 99 * ms)])
+    # one complete episode [0, 40 ms]; the one cut by the trace's end holds
+    # the second update, whose all-reduce is left out
+    host = [tr.Event("repro/episode", 0, 40 * ms),
+            tr.Event("repro/episode", 41 * ms, 200 * ms)]
+    red = tr.Reduced([chip, chip._replace(index=1)], host)
     ctx = {"trace": red, "episodes": 2}
     assert rollout_ms_per_ep.read(ctx) == pytest.approx(15.0)
-    assert learner_ms_per_ep.read(ctx) == pytest.approx(5.0)
-    assert collective_ms_per_ep.read(ctx) == pytest.approx(2.0)
+    assert learner_ms_per_ep.read(ctx) == pytest.approx(7.5)
+    assert collective_ms_per_ep.read(ctx) == pytest.approx(3.0)
 
 
 def test_breakdown_names_ops_and_counts_self_time():
@@ -63,7 +74,8 @@ def test_breakdown_names_ops_and_counts_self_time():
 
 
 def test_no_collectives_reads_nothing():
-    red = tr.Reduced([_chip([("fusion.1", 0, 10)])], [])
+    red = tr.Reduced([_chip([("fusion.1", 0, 10)], [("jit_update(1)", 0, 10)])],
+                     [tr.Event("repro/episode", 0, 10)])
     assert collective_ms_per_ep.read({"trace": red, "episodes": 1}) is None
     assert rollout_ms_per_ep.read({"trace": red, "episodes": 1}) is None
 

@@ -9,8 +9,9 @@ planes hold the host threads' activity on the same clock.
   overlap count once;
 - program time: the summed durations of a program's module events, the
   program named as jitted (``jit_<name>`` in the trace);
-- collective time: the summed durations of collective operations
-  (all-reduce, all-gather, reduce-scatter, collective-permute, all-to-all);
+- collective operations (all-reduce, all-gather, reduce-scatter,
+  collective-permute, all-to-all), by their instruction names
+  (``COLLECTIVE``; ``bench/metrics/collective_ms_per_ep.py``);
 - idle gaps: the spaces between busy intervals, each labelled with the
   shortest host event that covers its midpoint (what the host was doing);
 - operation time: each operation's duration less that of the operations
@@ -97,14 +98,6 @@ class Reduced:
     def program_s(self, program: str) -> float:
         return sum(e.end - e.start for c in self.chips for e in c.modules
                    if module_program(e.name) == program) / 1e9 / len(self.chips)
-
-    def collective_s(self) -> float:
-        return sum(e.end - e.start for c in self.chips for e in c.ops
-                   if COLLECTIVE.search(e.name)) / 1e9 / len(self.chips)
-
-    def has_collectives(self) -> bool:
-        return any(COLLECTIVE.search(e.name) for c in self.chips
-                   for e in c.ops)
 
     def idle_gaps(self) -> List[tuple]:
         """(label, seconds) of every gap between busy intervals, all chips,

@@ -28,6 +28,7 @@ from repro.cfd import scenarios as scn_mod
 from repro.cfd import solver
 from repro.cfd.grid import GridConfig, build_geometry
 from repro.cfd.scenarios import Scenario, ScenarioParams
+from repro.core import spans
 from repro.testing import faults
 
 
@@ -306,10 +307,13 @@ class CylinderEnv:
                 lambda re, m: self._run_steps(n, flow0, jnp.float32(0.0),
                                               re=re, act_mode=m,
                                               geom_arrays=ga)))
-            flows, (cds, _) = run(
-                jnp.asarray([g[0] for g in gtodo], jnp.float32),
-                jnp.asarray([g[1] for g in gtodo], jnp.float32))
-            cd0s = np.asarray(jnp.mean(cds[:, -tail:], axis=1))
+            # the compile or cache load, the run, and the C_D0 read that
+            # waits for it
+            with spans.setup_span("warmup"):
+                flows, (cds, _) = run(
+                    jnp.asarray([g[0] for g in gtodo], jnp.float32),
+                    jnp.asarray([g[1] for g in gtodo], jnp.float32))
+                cd0s = np.asarray(jnp.mean(cds[:, -tail:], axis=1))
             for i, g in enumerate(gtodo):
                 flow = jax.tree.map(lambda a, i=i: np.asarray(a[i]), flows)
                 self._group_cache[g] = (solver.FlowState(*flow),

@@ -26,6 +26,15 @@ from repro.cfd.grid import GridConfig, build_geometry
 TOL_ST = 0.015
 TOL_CD = 0.01
 TOL_AMP = 0.05
+TOL = {"strouhal": TOL_ST, "cd_mean": TOL_CD, "cl_amp": TOL_AMP}
+# The fluidic pinball's golden (tests/test_golden_pinball.py, chip_smoke.py),
+# calibrated on its own re-measure window:
+#   upwind_blend 0.2->0.25:  CD +1.2%, amp -11.8%      -> cd_mean / cl_amp
+#   upwind_blend 0.2->0.3:   St -1.7%, CD +2.3%        -> strouhal / cd_mean
+#   effective Re off by 10%: CD -1.8%, amp +12.7%      -> cd_mean / cl_amp
+# (penal_eta x2 moves nothing above 0.6%: penalization stiffness is not a
+# physics knob at this resolution)
+PINBALL_TOL = {"strouhal": 0.015, "cd_mean": 0.01, "cl_amp": 0.06}
 
 
 def run_uncontrolled(cfg: GridConfig, state: solver.FlowState, n: int,

@@ -16,17 +16,18 @@ starts before the session or ends after it is not recorded at all.
     repro/io.ckpt      checkpoint snapshot and save
     repro/io.interface the CFD<->DRL interface exchange
     repro/caller       the caller's per-episode hook
+    repro/setup.warmup one geometry's uncontrolled warm-up run before
+                       training (``CylinderEnv._warmup_groups``): its
+                       compile or cache load, the run and the C_D0 read
+
+The set-up spans and their record of host seconds live in
+``repro.core.spans``, which the env's layer imports too.
 """
 from __future__ import annotations
 
-from jax.profiler import StepTraceAnnotation, TraceAnnotation
+from jax.profiler import StepTraceAnnotation
 
-PREFIX = "repro/"
-
-
-def span(kind: str) -> TraceAnnotation:
-    """``with span("update"): ...`` records ``repro/update``."""
-    return TraceAnnotation(PREFIX + kind)
+from repro.core.spans import PREFIX, span
 
 
 def episode(index: int) -> StepTraceAnnotation:

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.cfd.env import CylinderEnv, EnvConfig
 from repro.ckpt import checkpoint as ckpt_mod
+from repro.core import spans as core_spans
 from repro.drl import networks, spans
 from repro.drl import train_state as ts_mod
 from repro.drl.engine import (EngineConfig, RolloutEngine, SinkSpec,
@@ -121,6 +122,8 @@ def train(cfg: TrainConfig, *, log_fn: Optional[Callable] = print,
     the same numbers stored under ``"health"`` in checkpoint metadata.
     ``_rollbacks``/``_sink_retries0`` are internal: the watchdog-rollback
     retry depth and the retries counted by pre-rollback engine sinks."""
+    if not _rollbacks:
+        core_spans.setup_seconds.clear()   # this run's set-up spans only
     resolved = mesh = None
     backend = None
     n_envs = cfg.n_envs

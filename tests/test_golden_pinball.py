@@ -21,20 +21,17 @@ import pytest
 
 from repro.cfd import solver
 from repro.cfd.grid import GridConfig
-from repro.cfd.validation import measure_shedding, run_uncontrolled
+from repro.cfd.validation import (PINBALL_TOL, measure_shedding,
+                                  run_uncontrolled)
 
 GOLDEN = Path(__file__).parent / "golden" / "pinball_re100_res8.npz"
 
 # Relative tolerances, mutation-calibrated on the re-measure window (the
-# re-measurement itself is bit-exact on the generating platform):
-#   upwind_blend 0.2->0.25:  CD +1.2%, amp -11.8%      -> TOL_CD / TOL_AMP
-#   upwind_blend 0.2->0.3:   St -1.7%, CD +2.3%        -> TOL_ST / TOL_CD
-#   effective Re off by 10%: CD -1.8%, amp +12.7%      -> TOL_CD / TOL_AMP
-# (penal_eta x2 moves nothing above 0.6% — penalization stiffness is not
-# a physics knob at this resolution)
-TOL_ST = 0.015
-TOL_CD = 0.01
-TOL_AMP = 0.06
+# re-measurement itself is bit-exact on the generating platform; the
+# mutations are listed beside ``PINBALL_TOL``)
+TOL_ST = PINBALL_TOL["strouhal"]
+TOL_CD = PINBALL_TOL["cd_mean"]
+TOL_AMP = PINBALL_TOL["cl_amp"]
 
 
 def _restart():

@@ -15,6 +15,7 @@ import pytest
 from repro.cfd.env import CylinderEnv, EnvConfig
 from repro.cfd.grid import GridConfig
 from repro.drl import networks
+from repro.core import spans as core_spans
 from repro.drl.engine import (EngineConfig, RolloutEngine,
                               broadcast_env_state)
 from repro.drl.ppo import PPOConfig
@@ -25,6 +26,10 @@ N_ENVS = 2
 ENV = EnvConfig(grid=GridConfig(res=6, dt=0.012, poisson_iters=10),
                 steps_per_action=2, actions_per_episode=3, warmup_time=0.1)
 PPO = PPOConfig(epochs=2, minibatches=2)
+PINBALL = EnvConfig(grid=GridConfig(res=4, dt=0.012, poisson_iters=10),
+                    steps_per_action=2, actions_per_episode=3,
+                    warmup_time=0.1, probe_layout="pinball",
+                    actuation="rotary", geometry="pinball")
 # the reads train()'s own loop makes each episode besides the watchdog's
 # one per PPO metric: the return (run_sync), reward, C_D, C_L, quarantines
 # and grad skips (on_episode)
@@ -75,7 +80,30 @@ def test_one_episode_step_span_per_episode(traced):
     assert [int(e[3]["step_num"]) for e in eps] == list(range(EPISODES))
     assert all(e0[2] <= e1[1] for e0, e1 in zip(eps, eps[1:]))
     # every span of the loop lies inside its episode
-    assert all(any(_inside(s, e) for e in eps) for s in spans)
+    assert all(any(_inside(s, e) for e in eps) for s in spans
+               if not s[0].startswith("repro/setup."))
+
+
+def test_warmup_span_before_the_first_episode(traced):
+    spans, _ = traced
+    warm = [s for s in spans if s[0] == "repro/setup.warmup"]
+    assert len(warm) == 1
+    assert warm[0][2] <= _episodes(spans)[0][1]
+
+
+def test_setup_record_holds_one_warmup_per_geometry_of_the_last_run():
+    """``train()`` clears the record of set-up seconds when it starts; its
+    warm-up adds one entry per geometry it runs."""
+    cfg = TrainConfig(env=PINBALL, ppo=PPO, n_envs=3, episodes=1, seed=0,
+                      scenarios=("pinball_re100", "pinball_re130",
+                                 "cyl_re100"))
+    train(cfg, log_fn=None)
+    seconds = core_spans.setup_seconds["repro/setup.warmup"]
+    assert len(seconds) == 2 and all(t > 0 for t in seconds)
+    train(TrainConfig(env=PINBALL, ppo=PPO, n_envs=2, episodes=1, seed=0),
+          log_fn=None)
+    assert list(core_spans.setup_seconds) == ["repro/setup.warmup"]
+    assert len(core_spans.setup_seconds["repro/setup.warmup"]) == 1
 
 
 @pytest.mark.parametrize("kind", ["collect", "update", "sync",
@@ -131,3 +159,21 @@ def test_named_scope_in_compiled_program(compiled_text, program, scope):
     # reads wrapped, as in ``jvp(ppo_loss)``
     assert re.search(rf'op_name="[^"]*[/(]{re.escape(scope)}[/)]',
                      compiled_text[program]), (program, scope)
+
+
+def test_per_body_einsums_carry_momentum_and_forces_scopes():
+    """The pinball's per-body rotary targets and force rows are named in
+    the compiled rollout, so the chip's compiled text maps their fusions
+    back to ``momentum`` and ``momentum/forces``."""
+    env = CylinderEnv(PINBALL)
+    st, obs = env.reset()
+    st_b, obs_b = broadcast_env_state(st, obs, N_ENVS)
+    engine = RolloutEngine.for_env(
+        env, EngineConfig(n_envs=N_ENVS,
+                          horizon=PINBALL.actions_per_episode))
+    pcfg = networks.PolicyConfig(obs_dim=int(obs.shape[-1]), act_dim=3)
+    params, _, _, key = engine.init(pcfg, PPO, seed=0)
+    text = engine._rollout.lower(params, st_b, obs_b, key).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for path in ("/momentum/b,byx->yx/", "/momentum/forces/byx,yx->b/"):
+        assert any(path in n for n in names), path
